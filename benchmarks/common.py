@@ -7,7 +7,8 @@ record the real values instead of re-parsing its own printout (the old
 ``line.split(",", 2)`` silently mis-parsed any non-CSV output line).
 
 ``bw_fields`` attaches the roofline columns — achieved GB/s against the
-backend's streaming-bandwidth ceiling (``launch/roofline.py``) — and
+device's published HBM ceiling (``launch/roofline.PEAKS``; a device
+without published peaks, such as a CPU, gets no ceiling) — and
 ``env_metadata`` captures the environment block every BENCH_*.json needs to
 stay interpretable (backend, devices, jax version, git sha, timestamp).
 """
@@ -113,13 +114,18 @@ def row(name: str, us: float, **derived) -> Row:
 # --------------------------------------------------------------------------
 
 def bw_fields(n_bytes: float, us: float) -> Dict[str, float]:
-    """Roofline accounting for a row that streams ``n_bytes``: achieved GB/s,
-    the backend's bandwidth ceiling, and the fraction of it reached."""
-    from repro.launch.roofline import mem_bw
+    """Roofline accounting for a row that streams ``n_bytes``: achieved GB/s
+    and, on a device with published peaks (``launch/roofline.PEAKS``), its
+    HBM ceiling and the fraction of it reached. A device without peaks (a
+    CPU) gets no roofline columns."""
+    from repro.launch.roofline import PEAKS, device_kind
+    kind = device_kind()
     gbps = n_bytes / us / 1e3 if us > 0 else 0.0   # bytes/µs -> GB/s
-    roof = mem_bw() / 1e9
-    return {"gbps": round(gbps, 3), "roof_gbps": round(roof, 1),
-            "roof_frac": round(gbps / roof, 4) if roof else 0.0}
+    out = {"gbps": round(gbps, 3)}
+    if kind in PEAKS:
+        roof = PEAKS[kind].hbm_bw / 1e9
+        out.update(roof_gbps=round(roof, 1), roof_frac=round(gbps / roof, 4))
+    return out
 
 
 # --------------------------------------------------------------------------

@@ -4,8 +4,8 @@ Sweeps n × fan_in through the TopSort two-phase driver and prices every row
 against the ``external_sort_bytes`` traffic model: one run-formation pass
 plus ``ceil(log_fan_in(runs))`` streamed run-merge passes, 2·n·itemsize
 each. The ``gbps``/``roof_frac`` columns are achieved streaming bandwidth
-vs the backend ceiling (``REPRO_MEM_BW_GBPS`` overrides it on containers
-the coarse table misclassifies), and every row is oracle-checked — the
+vs the device's published HBM ceiling (none on a device without published
+peaks), and every row is oracle-checked — the
 ``exact`` column is a hard bit-for-bit comparison against ``np.sort`` /
 stable argsort, not a statistic.
 

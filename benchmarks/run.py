@@ -47,6 +47,8 @@ def main(argv=None) -> None:
                          "(CI passes its own; defaults to now, UTC)")
     args = ap.parse_args(argv)
 
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (argsort_bench, external_sort_bench, fig14_w_sweep,
                             fig15_full_sort, kernel_merge, merge_tree_bench,
                             moe_dispatch, moe_route_bench, serve_bench,
@@ -61,7 +63,7 @@ def main(argv=None) -> None:
                 (argsort_bench, "Argsort variants (payload lanes)"),
                 (moe_dispatch, "MoE dispatch via repro.engine"),
                 (moe_route_bench, "DESIGN §9 (fused MoE routing op)"),
-                (sharded_sort_bench, "S8.2 (sharded sample sort, 8 devices)"),
+                (sharded_sort_bench, "S8.2 (sharded sample sort, all devices)"),
                 (external_sort_bench, "DESIGN §8 (out-of-core external sort)"),
                 (serve_bench, "DESIGN §10 (continuous-batching serve)")]
     if args.only:

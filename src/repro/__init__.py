@@ -1,4 +1,1 @@
 """FLiMS reproduction, grown into a production jax_pallas sorting stack."""
-from repro import compat as _compat
-
-_compat.install()

@@ -17,6 +17,7 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
 from repro.core.butterfly import bitonic_sort
 from repro.core.lanes import (KEY, RANK, VAL, sentinel_for, stable_compare,
@@ -28,6 +29,30 @@ def _next_pow2(n: int) -> int:
     while p < n:
         p *= 2
     return p
+
+
+#: widest padded row one top-k network sorts at once; wider rows go through
+#: ``lax.map`` one chunk at a time, so the network's stage temporaries stay
+#: at one chunk's size (a 2^24-key row otherwise needs more than 16 GB)
+CHUNK = 1 << 18
+
+
+def _reduce_rows(rows):
+    """Tree-reduce sorted k-rows pairwise along axis -2 to one row."""
+    while rows[KEY].shape[-2] > 1:
+        m = rows[KEY].shape[-2]
+        if m % 2 == 1:  # carry odd row through
+            carry = jax.tree.map(lambda r: r[..., -1:, :], rows)
+            rows = jax.tree.map(lambda r: r[..., :-1, :], rows)
+        else:
+            carry = None
+        a = jax.tree.map(lambda r: r[..., 0::2, :], rows)
+        b = jax.tree.map(lambda r: r[..., 1::2, :], rows)
+        rows = topk_node(a, b, stable_compare)
+        if carry is not None:
+            rows = jax.tree.map(lambda r, c: jnp.concatenate([r, c], axis=-2),
+                                rows, carry)
+    return rows
 
 
 @partial(jax.jit, static_argnames=("k",))
@@ -56,21 +81,20 @@ def flims_topk(x: jnp.ndarray, k: int, values=None):
         rows[VAL] = jax.tree.map(
             lambda v: jnp.pad(v, pad).reshape(x.shape[:-1] + (n_pad // kk, kk)),
             values)
-    rows = bitonic_sort(rows, compare=stable_compare)
-    # tree-reduce rows pairwise along axis -2
-    while rows[KEY].shape[-2] > 1:
-        m = rows[KEY].shape[-2]
-        if m % 2 == 1:  # carry odd row through
-            carry = jax.tree.map(lambda r: r[..., -1:, :], rows)
-            rows = jax.tree.map(lambda r: r[..., :-1, :], rows)
-        else:
-            carry = None
-        a = jax.tree.map(lambda r: r[..., 0::2, :], rows)
-        b = jax.tree.map(lambda r: r[..., 1::2, :], rows)
-        rows = topk_node(a, b, stable_compare)
-        if carry is not None:
-            rows = jax.tree.map(lambda r, c: jnp.concatenate([r, c], axis=-2),
-                                rows, carry)
+    if kk < CHUNK < n_pad:
+        # one chunk's network at a time: top-k of a union is the top-k of
+        # the parts' top-k, and the rank lane orders every tie, so the
+        # chunked result is the same to the bit
+        c = n_pad // CHUNK
+        parts = jax.tree.map(lambda r: jnp.moveaxis(
+            r.reshape(r.shape[:-2] + (c, CHUNK // kk, kk)), -3, 0), rows)
+        tops = lax.map(lambda p: _reduce_rows(
+            bitonic_sort(p, compare=stable_compare)), parts)
+        rows = jax.tree.map(lambda r: jnp.moveaxis(r[..., 0, :], 0, -2),
+                            tops)
+    else:
+        rows = bitonic_sort(rows, compare=stable_compare)
+    rows = _reduce_rows(rows)
     vals = rows[KEY][..., 0, :k]
     inds = rows[RANK][..., 0, :k]
     # rank validity: padding carries ranks >= n, so it can only surface when

@@ -670,10 +670,10 @@ def moe_route(logits, k: int, capacity: int, *, values=None,
     scattering ``x[tokens]`` to ``slabs`` builds the (E, capacity, d) expert
     slabs directly and ``weights * keep`` are the combine coefficients.
 
-    The ``fused`` variant executes softmax, top-k, the stable expert sort
-    (riding the FLiMS merge-tree dataflow), and the capacity drop in ONE
-    ``pallas_call`` per chunk — no intermediate touches HBM; ``xla`` is the
-    unfused reference pipeline. ``values=`` (leaves shaped like one logit
+    The ``fused`` variant executes top-k, the stable expert sort and the
+    capacity drop in ONE ``pallas_call`` per chunk, and the softmax
+    combine weights with the same XLA ops as ``xla``, the unfused
+    reference pipeline. ``values=`` (leaves shaped like one logit
     column, i.e. (G, T)) gathers a payload by ``tokens`` and returns
     ``(RouteResult, routed_values)``.
     """

@@ -302,14 +302,6 @@ def candidate_plans(op: str, key: Key):
             if variant.endswith("two_phase"):
                 # phase 2 is a MergeSchedule: also sweep the fused depth
                 out.append(Plan(variant, w=32, chunk=256, levels=2))
-        elif op in ("moe_route", "moe_route_ep"):
-            # routing dofs: the in-kernel bitonic chunk width of the fused
-            # megakernel (the xla reference has no tile parameters)
-            if variant == "fused":
-                out.extend(Plan(variant, w=32, chunk=chunk)
-                           for chunk in (256, 512))
-            else:
-                out.append(Plan(variant, w=32))
         else:
             out.append(Plan(variant))
     return out

@@ -108,7 +108,7 @@ def _sort_ref(x, *, plan, interpret):
 @register("sort", "pallas")
 def _sort_pallas(x, *, plan, interpret):
     from repro.kernels.ops import kernel_sort
-    return kernel_sort(x, chunk=plan.chunk, w=plan.w)
+    return kernel_sort(x, chunk=plan.chunk, w=plan.w, interpret=interpret)
 
 
 @register("sort", "xla")
@@ -221,8 +221,7 @@ for _v in ("flims", "xla"):
 @register("moe_route", "fused")
 def _moe_route_fused(logits, k, capacity, *, plan, interpret):
     from repro.kernels.route_fuse import moe_route_pallas
-    return moe_route_pallas(logits, k, capacity, chunk=plan.chunk,
-                            w=plan.w, interpret=interpret)
+    return moe_route_pallas(logits, k, capacity, interpret=interpret)
 
 
 @register("moe_route", "xla")

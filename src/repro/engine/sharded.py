@@ -400,7 +400,7 @@ def _emit_route_ep(arrived, dropped) -> None:
 
 
 def _route_ep_pass(lg, *, axis_name: str, n_dev: int, k: int, cap: int,
-                   local_variant: str, chunk: int, w: int, interpret: bool,
+                   local_variant: str, w: int, interpret: bool,
                    record: bool):
     """Per-device EP pipeline: fused-route the local token rows (the local
     capacity cut doubling as the sharded-topk union-of-local-top-k
@@ -424,8 +424,7 @@ def _route_ep_pass(lg, *, axis_name: str, n_dev: int, k: int, cap: int,
     A = min(Npl, E_loc * cap)      # kept-per-owner bound: both are hard caps
     span = n_dev * Npl             # one expert's band of global pair ranks
     if local_variant == "fused":
-        route = moe_route_pallas(lg[None], k, cap, chunk=chunk, w=w,
-                                 interpret=interpret)
+        route = moe_route_pallas(lg[None], k, cap, interpret=interpret)
     else:
         route = moe_route_xla(lg[None], k, cap)
     e_s, _t_s, perm, w_s, _slab, keep = (x[0] for x in route)
@@ -512,7 +511,7 @@ def _route_ep_impl(logits, k, capacity, mesh, axis, plan, record):
               local_variant=local_variant)
     fn = partial(_route_ep_pass, axis_name=axis, n_dev=n_dev, k=k,
                  cap=int(capacity), local_variant=local_variant,
-                 chunk=plan.chunk, w=plan.w, interpret=default_interpret(),
+                 w=plan.w, interpret=default_interpret(),
                  record=record)
     spec = RouteShard(*([P(axis)] * 7))
     return jax.shard_map(fn, mesh=mesh, in_specs=P(axis), out_specs=spec,

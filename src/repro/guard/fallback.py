@@ -12,6 +12,9 @@ ladder applies to bucket overflow.
 
 Every demotion is visible, never silent:
 
+- a one-line warning on stderr, written whether or not ``repro.obs`` is
+  on — a kernel that fails on the chip must not quietly turn into the
+  reference;
 - ``guard.fallback`` event + counter — which variant failed, which rung
   absorbed the call, and the truncated error.
 - ``guard.quarantine`` event + counter — the failing ``(op, variant,
@@ -28,6 +31,7 @@ another variant would just fail differently.
 """
 from __future__ import annotations
 
+import sys
 from typing import Optional
 
 from repro import obs
@@ -117,6 +121,9 @@ def guarded_call(op: str, plan, *args, **kw):
                 default_planner.quarantine(key, p)
                 obs.event("guard.quarantine", op=op, variant=variant,
                           key=_key_str(key))
+            print(f"repro.guard: {op} variant {variant!r} failed "
+                  f"({type(e).__name__}: {str(e)[:160]}); demoted to "
+                  f"{rungs[i + 1]!r}", file=sys.stderr, flush=True)
             obs.inc("guard.fallback")
             obs.inc("guard.quarantine")
             obs.event("guard.fallback", op=op, from_variant=variant,

@@ -3,7 +3,7 @@
 The sort-in-chunks stage of the paper's complete sorter (§8.2, chunk=512).
 Each grid step sorts a (rows_per_block, chunk) VMEM tile descending along the
 trailing axis with the full bitonic network — log2(c)(log2(c)+1)/2 stages of
-static reshapes + min/max, i.e. pure VPU work with no dynamic shuffles.
+native lane rotates + min/max, i.e. pure VPU work with no dynamic shuffles.
 """
 from __future__ import annotations
 
@@ -11,29 +11,45 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.flims_merge import lane_first, lane_partner
+
+
+def _stages(n: int):
+    """The bitonic network's (k, d) stages for n (a power of two) keys."""
+    k = 2
+    while k <= n:
+        d = k // 2
+        while d >= 1:
+            yield k, d
+            d //= 2
+        k *= 2
 
 
 def _bitonic_rows_desc(x: jnp.ndarray) -> jnp.ndarray:
-    """Sort each row of (m, c) descending; c a power of two. Static network."""
-    m, c = x.shape
-    k = 2
-    while k <= c:
-        half = k // 2
-        d = half
-        while d >= 1:
-            y = x.reshape(m, c // (2 * d), 2, d)
-            top, bot = y[:, :, 0, :], y[:, :, 1, :]
-            first = (jnp.arange(c).reshape(c // (2 * d), 2, d)[:, 0, :])
-            asc = ((first // k) % 2 == 1)            # odd k-blocks ascend
-            mx = jnp.maximum(top, bot)
-            mn = jnp.minimum(top, bot)
-            hi = jnp.where(asc[None], mn, mx)
-            lo = jnp.where(asc[None], mx, mn)
-            x = jnp.stack([hi, lo], axis=2).reshape(m, c)
-            d //= 2
-        k *= 2
+    """Sort each row of (m, c) descending; c a power of two. Static network:
+    each stage compares every key with its XOR-partner lane; blocks whose
+    bit ``k`` is set sort ascending, so the final pass (``k == c``) leaves
+    every row descending."""
+    i = lax.broadcasted_iota(jnp.int32, x.shape, 1)
+    for k, d in _stages(x.shape[1]):
+        hi = (i & d) != 0
+        p = lane_partner(x, d, hi, 1)
+        asc = (i & k) != 0
+        x = jnp.where(hi != asc, jnp.minimum(x, p), jnp.maximum(x, p))
     return x
+
+
+def _keep_first(first, k, r, pk, pr, hi, asc):
+    """One compare-exchange of the compound network: every element keeps
+    itself or takes its partner so the lower index of each pair holds the
+    one that orders first (last, in ascending blocks)."""
+    top_first = (hi & first(pk, pr, k, r)) | (~hi & first(k, r, pk, pr))
+    keep = top_first ^ asc
+    return jnp.where(keep, k, pk), jnp.where(keep, r, pr)
 
 
 def _bitonic_rows_kv(k: jnp.ndarray, r: jnp.ndarray,
@@ -44,30 +60,72 @@ def _bitonic_rows_kv(k: jnp.ndarray, r: jnp.ndarray,
     input order this is a stable sort. Same static network as
     ``_bitonic_rows_desc``, with the compound comparator on both lanes.
     """
-    m, c = k.shape
-    kk = 2
-    while kk <= c:
-        half = kk // 2
-        d = half
-        while d >= 1:
-            ks = k.reshape(m, c // (2 * d), 2, d)
-            rs = r.reshape(m, c // (2 * d), 2, d)
-            kt, kb = ks[:, :, 0, :], ks[:, :, 1, :]
-            rt, rb = rs[:, :, 0, :], rs[:, :, 1, :]
-            first = (jnp.arange(c).reshape(c // (2 * d), 2, d)[:, 0, :])
-            asc = ((first // kk) % 2 == 1)[None]      # odd kk-blocks reverse
-            if descending:
-                top_first = (kt > kb) | ((kt == kb) & (rt < rb))
-            else:
-                top_first = (kt < kb) | ((kt == kb) & (rt < rb))
-            keep = top_first ^ asc
-            k = jnp.stack([jnp.where(keep, kt, kb),
-                           jnp.where(keep, kb, kt)], axis=2).reshape(m, c)
-            r = jnp.stack([jnp.where(keep, rt, rb),
-                           jnp.where(keep, rb, rt)], axis=2).reshape(m, c)
-            d //= 2
-        kk *= 2
+    first = lane_first(descending)
+    i = lax.broadcasted_iota(jnp.int32, k.shape, 1)
+    for kk, d in _stages(k.shape[1]):
+        hi = (i & d) != 0
+        pk, pr = lane_partner(k, d, hi, 1), lane_partner(r, d, hi, 1)
+        k, r = _keep_first(first, k, r, pk, pr, hi, (i & kk) != 0)
     return k, r
+
+
+def sort_flat_ref(k_ref, r_ref=None, *, descending: bool = True) -> None:
+    """Sort an (R, 128) key ref in place as ONE row-major sequence — with
+    ``r_ref``, (key, rank) pairs by the compound order — through the
+    bitonic network over all ``R * 128`` elements (a power of two),
+    streamed through (8, 128) tiles so the code is one small loop per stage
+    however large the bank:
+
+    - partners less than a row apart: lane rotates inside the tile;
+    - partners less than a tile apart: sublane rotates inside the tile;
+    - partners whole tiles apart: a compare-exchange of the two tiles.
+    """
+    R, c = k_ref.shape
+    TR = 8                                   # rows of one (8, 128) tile
+    refs = (k_ref,) if r_ref is None else (k_ref, r_ref)
+    if r_ref is None:
+        first = (lambda a, b: a[0] > b[0]) if descending else \
+            (lambda a, b: a[0] < b[0])
+    else:
+        kv_first = lane_first(descending)
+        first = lambda a, b: kv_first(a[0], a[1], b[0], b[1])
+    shape = (TR, c)
+    row = lax.broadcasted_iota(jnp.int32, shape, 0)
+    lane = lax.broadcasted_iota(jnp.int32, shape, 1)
+
+    def tile(t):
+        return pl.ds(pl.multiple_of(t * TR, TR), TR)
+
+    for kk, d in _stages(R * c):
+        if d < c * TR:                       # partner inside the tile
+            def body(t, carry, kk=kk, d=d):
+                sl = tile(t)
+                x = [ref[sl, :] for ref in refs]
+                i = (t * TR + row) * c + lane
+                hi = (i & d) != 0
+                axis, sh = (1, d) if d < c else (0, d // c)
+                p = [lane_partner(v, sh, hi, axis) for v in x]
+                top_first = (hi & first(p, x)) | (~hi & first(x, p))
+                keep = top_first ^ ((i & kk) != 0)
+                for ref, v, pv in zip(refs, x, p):
+                    ref[sl, :] = jnp.where(keep, v, pv)
+                return carry
+            lax.fori_loop(0, R // TR, body, 0)
+        else:                                # partner tile t ^ D
+            D = d // (c * TR)
+
+            def body(u, carry, kk=kk, D=D):
+                t = (u // D) * 2 * D + u % D  # the pair's lower tile
+                sa, sb = tile(t), tile(t + D)
+                a = [ref[sa, :] for ref in refs]
+                b = [ref[sb, :] for ref in refs]
+                asc = (((t * TR + row) * c + lane) & kk) != 0
+                keep = first(a, b) ^ asc
+                for ref, va, vb in zip(refs, a, b):
+                    ref[sa, :] = jnp.where(keep, va, vb)
+                    ref[sb, :] = jnp.where(keep, vb, va)
+                return carry
+            lax.fori_loop(0, R // TR // 2, body, 0)
 
 
 def _sort_kernel(x_ref, o_ref):

@@ -9,11 +9,13 @@ rotation invariant (lA + lB) ≡ 0 (mod w) holds at every partition boundary
 mid-rotation with *zero* realignment work.
 
 Memory behaviour per grid step (the TPU adaptation of the paper's banked
-BRAM): A and B arrive as row-major (rows, w) arrays; the BlockSpec brings in
-only the C/w + 2 rows each side can consume (``pl.Element`` indexing driven by
-the scalar-prefetched co-ranks), and the inner loop issues only row-aligned
-sublane loads — the lane-rotation that a naive vectorised merge would need is
-algebraically eliminated, exactly the paper's core trick.
+BRAM): A and B stay in HBM as row-major (rows, w) banks; each step DMAs only
+the C/w + 2 rows each side can consume (the window starts at the step's
+co-rank row, read from SMEM, which need not be tile-aligned), and the inner
+loop issues only (1, w) row loads — the lane-rotation that a naive vectorised
+merge would need is algebraically eliminated, exactly the paper's core trick.
+Lane permutations inside a step (the B-side reversal, the butterfly's
+partner exchange) are built from the TPU's native lane rotate.
 """
 from __future__ import annotations
 
@@ -55,39 +57,98 @@ def lane_first(descending: bool = True):
     return lambda ka, ra, kb, rb: (ka < kb) | ((ka == kb) & (ra < rb))
 
 
-def element_block_spec(n_rows: int, w: int, index_map) -> pl.BlockSpec:
-    """(n_rows, w) input block addressed at *element* granularity in dim 0.
+def pad_rows(n: int) -> int:
+    """Rows of a VMEM window holding ``n`` bank rows: the sublane tile (8)."""
+    return -(-n // 8) * 8
 
-    JAX >= 0.5 spells this ``pl.Element``; 0.4.x spells it
-    ``indexing_mode=pl.Unblocked()``. Either way ``index_map`` must return the
-    starting row in elements (the lane dim is always full-width at 0).
-    """
-    if hasattr(pl, "Element"):
-        return pl.BlockSpec((pl.Element(n_rows), w), index_map)
-    return pl.BlockSpec((n_rows, w), index_map,
-                        indexing_mode=pl.Unblocked())
+
+def lane_partner(v: jnp.ndarray, d: int, hi: jnp.ndarray,
+                 axis: int = -1) -> jnp.ndarray:
+    """Each element's XOR-``d`` partner along ``axis`` (the lanes by
+    default): element ``i`` reads ``v[i ^ d]``. ``hi`` marks the elements
+    whose bit ``d`` is set (they read ``d`` places down). Two native TPU
+    rotates (``pltpu.roll`` follows ``jnp.roll``) and a select — Mosaic has
+    no gather and no shape cast that would do this otherwise."""
+    axis = axis % v.ndim
+    n = v.shape[axis]
+    return jnp.where(hi, pltpu.roll(v, d, axis), pltpu.roll(v, n - d, axis))
+
+
+def lane_iota(v: jnp.ndarray) -> jnp.ndarray:
+    return lax.broadcasted_iota(jnp.int32, v.shape, v.ndim - 1)
+
+
+def lane_rev(v: jnp.ndarray) -> jnp.ndarray:
+    """Reverse the lanes of ``v`` (w a power of two): lane ``i`` takes
+    ``i ^ (w-1)``, composed from one XOR-partner exchange per index bit —
+    Mosaic has no lane-reverse primitive, but rotates are native."""
+    iota = lane_iota(v)
+    d = 1
+    while d < v.shape[-1]:
+        v = lane_partner(v, d, (iota & d) != 0)
+        d *= 2
+    return v
 
 
 def _butterfly_desc(v: jnp.ndarray) -> jnp.ndarray:
-    """Sort a (rotated-)bitonic w-vector descending: log2(w) CAS stages."""
-    w = v.shape[-1]
-    d = w // 2
+    """Sort (rotated-)bitonic rows of ``v`` descending along the lanes:
+    log2(w) compare-exchange stages between XOR-partner lanes."""
+    iota = lane_iota(v)
+    d = v.shape[-1] // 2
     while d >= 1:
-        x = v.reshape(w // (2 * d), 2, d)
-        hi = jnp.maximum(x[:, 0, :], x[:, 1, :])
-        lo = jnp.minimum(x[:, 0, :], x[:, 1, :])
-        v = jnp.stack([hi, lo], axis=1).reshape(w)
+        hi = (iota & d) != 0
+        p = lane_partner(v, d, hi)
+        v = jnp.where(hi, jnp.minimum(v, p), jnp.maximum(v, p))
         d //= 2
     return v
 
 
-def _merge_kernel(arow0_ref, brow0_ref, la0_ref, lb0_ref,   # scalar prefetch
-                  a_ref, b_ref, out_ref, *, w: int, cycles: int):
-    g = pl.program_id(0)
-    lA0 = la0_ref[g]
-    lB0 = lb0_ref[g]
-    iota = lax.broadcasted_iota(jnp.int32, (w,), 0)
-    n_rows = a_ref.shape[0]
+def fetch_windows(windows, sem) -> None:
+    """Copy each ``(src, row, dst)`` window — ``dst.shape[0]`` rows of the
+    HBM bank ``src`` starting at (dynamic, unaligned) ``row`` — into its
+    VMEM scratch ``dst``; all copies are in flight together."""
+    copies = [pltpu.make_async_copy(src.at[pl.ds(row, dst.shape[0])], dst,
+                                    sem.at[i])
+              for i, (src, row, dst) in enumerate(windows)]
+    for c in copies:
+        c.start()
+    for c in copies:
+        c.wait()
+
+
+def row_reader(ref, n_rows: int):
+    """``read(r)`` → the (1, w) row ``r`` of a VMEM window, clamped to its
+    last real row (reads past a window's end only feed discarded state)."""
+    return lambda r: ref[pl.ds(jnp.minimum(r, n_rows - 1), 1), :]
+
+
+def row_blocks(G: int, C: int, w: int, dtype):
+    """Output geometry shared by the merge kernels: ``G`` blocks of ``C``
+    keys as (G, C/w, w) rows, one block per grid step — a block that spans
+    the array's trailing dims, which the TPU tiling accepts for any w."""
+    spec = pl.BlockSpec((1, C // w, w), lambda g, *_: (g, 0, 0))
+    return spec, jax.ShapeDtypeStruct((G, C // w, w), dtype)
+
+
+def meta_block(meta):
+    """Per-step scalars (G, M) → a (G, 1, M) SMEM operand whose (1, 1, M)
+    block is step ``g``'s row: the pipeline streams one row per step, so
+    SMEM holds two rows however long the grid (a scalar-prefetched table
+    of every step's scalars outgrows SMEM's 1 MiB at large ``G``)."""
+    G, M = meta.shape
+    spec = pl.BlockSpec((1, 1, M), lambda g, *_: (g, 0, 0),
+                        memory_space=pltpu.SMEM)
+    return spec, meta.astype(jnp.int32).reshape(G, 1, M)
+
+
+def _merge_kernel(m_ref, a_hbm, b_hbm, out_ref, a_win, b_win, sem, *,
+                  w: int, cycles: int, Ha: int):
+    fetch_windows([(a_hbm, m_ref[0, 0, 0], a_win),
+                   (b_hbm, m_ref[0, 0, 1], b_win)], sem)
+    lA0 = m_ref[0, 0, 2]
+    lB0 = m_ref[0, 0, 3]
+    iota = lax.broadcasted_iota(jnp.int32, (1, w), 1)
+    read_a, read_b = row_reader(a_win, Ha), row_reader(b_win, Ha)
 
     def heads(W0, W1, l):
         return jnp.where(iota < l, W1, W0)
@@ -95,27 +156,51 @@ def _merge_kernel(arow0_ref, brow0_ref, la0_ref, lb0_ref,   # scalar prefetch
     def body(t, carry):
         WA0, WA1, WB0, WB1, lA, lB, rA, rB = carry
         cA = heads(WA0, WA1, lA)
-        cBr = heads(WB0, WB1, lB)[::-1]     # MAX_i pairs a_i with b_{w-1-i}
+        cBr = lane_rev(heads(WB0, WB1, lB))  # MAX_i pairs a_i with b_{w-1-i}
         mask = cA > cBr                     # algorithm 1: ties dequeue from B
         chunk = _butterfly_desc(jnp.maximum(cA, cBr))
-        out_ref[0, pl.ds(t * w, w)] = chunk
+        out_ref[0, pl.ds(t, 1), :] = chunk
         k = jnp.sum(mask.astype(jnp.int32))
 
-        def advance(W0, W1, l, r, ref, consumed):
+        def advance(W0, W1, l, r, read, consumed):
             l2 = l + consumed
             shift = l2 >= w
-            nxt = ref[jnp.minimum(r, n_rows - 1), :]
+            nxt = read(r)
             W0n = jnp.where(shift, W1, W0)
             W1n = jnp.where(shift, nxt, W1)
             return W0n, W1n, jnp.where(shift, l2 - w, l2), r + shift.astype(jnp.int32)
 
-        WA0, WA1, lA, rA = advance(WA0, WA1, lA, rA, a_ref, k)
-        WB0, WB1, lB, rB = advance(WB0, WB1, lB, rB, b_ref, w - k)
+        WA0, WA1, lA, rA = advance(WA0, WA1, lA, rA, read_a, k)
+        WB0, WB1, lB, rB = advance(WB0, WB1, lB, rB, read_b, w - k)
         return WA0, WA1, WB0, WB1, lA, lB, rA, rB
 
-    init = (a_ref[0, :], a_ref[1, :], b_ref[0, :], b_ref[1, :],
+    init = (read_a(0), read_a(1), read_b(0), read_b(1),
             lA0, lB0, jnp.int32(2), jnp.int32(2))
     lax.fori_loop(0, cycles, body, init)
+
+
+def merge_call(kernel, banks, meta, *, G: int, C: int, w: int, Ha: int,
+               out_dtypes, name: str, interpret: bool, **kw):
+    """One merge ``pallas_call`` over ``G`` output blocks: the ``banks`` stay
+    in HBM and every grid step DMAs its ``Ha``-row window of each (rows
+    and initial rotations from step ``g``'s row of the (G, 4) ``meta``:
+    A row, B row, A rotation, B rotation); returns (G, C) per output
+    lane."""
+    Hp = pad_rows(Ha)
+    spec, _ = row_blocks(G, C, w, jnp.int32)
+    mspec, meta = meta_block(meta)
+    outs = pl.pallas_call(
+        functools.partial(kernel, w=w, cycles=C // w, Ha=Ha, **kw),
+        grid=(G,),
+        in_specs=[mspec] + [pl.BlockSpec(memory_space=pl.ANY)] * len(banks),
+        out_specs=[spec] * len(out_dtypes),
+        out_shape=[row_blocks(G, C, w, d)[1] for d in out_dtypes],
+        scratch_shapes=[pltpu.VMEM((Hp, w), b.dtype) for b in banks]
+        + [pltpu.SemaphoreType.DMA((len(banks),))],
+        interpret=interpret,
+        name=name,
+    )(meta, *banks)
+    return [o.reshape(G, C) for o in outs]
 
 
 def _corank(o, a, b):
@@ -175,7 +260,7 @@ def flims_merge_pallas(a: jnp.ndarray, b: jnp.ndarray, *, w: int = 128,
     sent = sentinel_for(a.dtype)
 
     def rows_of(x):
-        r = -(-x.shape[0] // w) + Ha + 2
+        r = -(-x.shape[0] // w) + pad_rows(Ha) + 2
         xp = jnp.pad(x, (0, r * w - x.shape[0]), constant_values=sent)
         return xp.reshape(r, w)
 
@@ -187,25 +272,10 @@ def flims_merge_pallas(a: jnp.ndarray, b: jnp.ndarray, *, w: int = 128,
     arow0, la0 = acut // w, acut % w
     brow0, lb0 = bcut // w, bcut % w
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(G,),
-        in_specs=[
-            element_block_spec(Ha, w,
-                               lambda g, ar0, br0, la, lb: (ar0[g], 0)),
-            element_block_spec(Ha, w,
-                               lambda g, ar0, br0, la, lb: (br0[g], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, C), lambda g, *_: (g, 0)),
-    )
-    kern = functools.partial(_merge_kernel, w=w, cycles=C // w)
-    out = pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((G, C), a.dtype),
-        interpret=interpret,
-        name="flims_merge",
-    )(arow0, brow0, la0, lb0, ar, br)
+    (out,) = merge_call(_merge_kernel, [ar, br],
+                        jnp.stack([arow0, brow0, la0, lb0], axis=1),
+                        G=G, C=C, w=w, Ha=Ha, out_dtypes=[a.dtype],
+                        name="flims_merge", interpret=interpret)
     return out.reshape(-1)[:n_out]
 
 
@@ -222,33 +292,33 @@ def flims_merge_pallas(a: jnp.ndarray, b: jnp.ndarray, *, w: int = 128,
 # engine layer.
 
 def _butterfly_kv(v: jnp.ndarray, r: jnp.ndarray, descending: bool = True):
-    """Butterfly CAS over (key, rank) lanes: log2(w) compound-compare stages."""
+    """Butterfly CAS over (key, rank) lanes: log2(w) compound-compare stages
+    between XOR-partner lanes (the lower lane of each pair keeps the one
+    that orders first)."""
     first = lane_first(descending)
-    w = v.shape[-1]
-    d = w // 2
+    iota = lane_iota(v)
+    d = v.shape[-1] // 2
     while d >= 1:
-        x = v.reshape(w // (2 * d), 2, d)
-        y = r.reshape(w // (2 * d), 2, d)
-        kt, kb = x[:, 0, :], x[:, 1, :]
-        rt, rb = y[:, 0, :], y[:, 1, :]
-        m = first(kt, rt, kb, rb)
-        v = jnp.stack([jnp.where(m, kt, kb), jnp.where(m, kb, kt)],
-                      axis=1).reshape(w)
-        r = jnp.stack([jnp.where(m, rt, rb), jnp.where(m, rb, rt)],
-                      axis=1).reshape(w)
+        hi = (iota & d) != 0
+        pv, pr = lane_partner(v, d, hi), lane_partner(r, d, hi)
+        keep = (hi & first(pv, pr, v, r)) | (~hi & first(v, r, pv, pr))
+        v, r = jnp.where(keep, v, pv), jnp.where(keep, r, pr)
         d //= 2
     return v, r
 
 
-def _merge_kv_kernel(arow0_ref, brow0_ref, la0_ref, lb0_ref,  # scalar prefetch
-                     a_ref, ar_ref, b_ref, br_ref, ok_ref, or_ref, *,
-                     w: int, cycles: int, descending: bool = True):
-    g = pl.program_id(0)
-    lA0 = la0_ref[g]
-    lB0 = lb0_ref[g]
-    iota = lax.broadcasted_iota(jnp.int32, (w,), 0)
-    n_rows = a_ref.shape[0]
+def _merge_kv_kernel(m_ref, a_hbm, ar_hbm, b_hbm, br_hbm, ok_ref, or_ref,
+                     a_win, ar_win, b_win, br_win, sem, *,
+                     w: int, cycles: int, Ha: int, descending: bool = True):
+    arow, brow = m_ref[0, 0, 0], m_ref[0, 0, 1]
+    fetch_windows([(a_hbm, arow, a_win), (ar_hbm, arow, ar_win),
+                   (b_hbm, brow, b_win), (br_hbm, brow, br_win)], sem)
+    lA0 = m_ref[0, 0, 2]
+    lB0 = m_ref[0, 0, 3]
+    iota = lax.broadcasted_iota(jnp.int32, (1, w), 1)
     first = lane_first(descending)
+    reads_a = (row_reader(a_win, Ha), row_reader(ar_win, Ha))
+    reads_b = (row_reader(b_win, Ha), row_reader(br_win, Ha))
 
     def heads(W0, W1, l):
         return jnp.where(iota < l, W1, W0)
@@ -257,34 +327,33 @@ def _merge_kv_kernel(arow0_ref, brow0_ref, la0_ref, lb0_ref,  # scalar prefetch
         (WA0, WA1, RA0, RA1, WB0, WB1, RB0, RB1, lA, lB, rA, rB) = carry
         cA = heads(WA0, WA1, lA)
         cAr = heads(RA0, RA1, lA)
-        cB = heads(WB0, WB1, lB)[::-1]      # MAX_i pairs a_i with b_{w-1-i}
-        cBr = heads(RB0, RB1, lB)[::-1]
+        cB = lane_rev(heads(WB0, WB1, lB))  # MAX_i pairs a_i with b_{w-1-i}
+        cBr = lane_rev(heads(RB0, RB1, lB))
         take = first(cA, cAr, cB, cBr)      # stable selector (algorithm 3)
         ck, cr = _butterfly_kv(jnp.where(take, cA, cB),
                                jnp.where(take, cAr, cBr), descending)
-        ok_ref[0, pl.ds(t * w, w)] = ck
-        or_ref[0, pl.ds(t * w, w)] = cr
+        ok_ref[0, pl.ds(t, 1), :] = ck
+        or_ref[0, pl.ds(t, 1), :] = cr
         k = jnp.sum(take.astype(jnp.int32))
 
-        def advance(W0, W1, R0, R1, l, r, kref, rref, consumed):
+        def advance(W0, W1, R0, R1, l, r, reads, consumed):
             l2 = l + consumed
             shift = l2 >= w
-            row = jnp.minimum(r, n_rows - 1)
             W0n = jnp.where(shift, W1, W0)
-            W1n = jnp.where(shift, kref[row, :], W1)
+            W1n = jnp.where(shift, reads[0](r), W1)
             R0n = jnp.where(shift, R1, R0)
-            R1n = jnp.where(shift, rref[row, :], R1)
+            R1n = jnp.where(shift, reads[1](r), R1)
             return (W0n, W1n, R0n, R1n, jnp.where(shift, l2 - w, l2),
                     r + shift.astype(jnp.int32))
 
         WA0, WA1, RA0, RA1, lA, rA = advance(WA0, WA1, RA0, RA1, lA, rA,
-                                             a_ref, ar_ref, k)
+                                             reads_a, k)
         WB0, WB1, RB0, RB1, lB, rB = advance(WB0, WB1, RB0, RB1, lB, rB,
-                                             b_ref, br_ref, w - k)
+                                             reads_b, w - k)
         return (WA0, WA1, RA0, RA1, WB0, WB1, RB0, RB1, lA, lB, rA, rB)
 
-    init = (a_ref[0, :], a_ref[1, :], ar_ref[0, :], ar_ref[1, :],
-            b_ref[0, :], b_ref[1, :], br_ref[0, :], br_ref[1, :],
+    init = (reads_a[0](0), reads_a[0](1), reads_a[1](0), reads_a[1](1),
+            reads_b[0](0), reads_b[0](1), reads_b[1](0), reads_b[1](1),
             lA0, lB0, jnp.int32(2), jnp.int32(2))
     lax.fori_loop(0, cycles, body, init)
 
@@ -356,7 +425,7 @@ def flims_merge_kv_pallas(a, ra, b, rb, *, w: int = 128,
     _, last = bound_keys(a.dtype, descending)
 
     def rows_of(x, fill):
-        r = -(-x.shape[0] // w) + Ha + 2
+        r = -(-x.shape[0] // w) + pad_rows(Ha) + 2
         xp = jnp.pad(x, (0, r * w - x.shape[0]), constant_values=fill)
         return xp.reshape(r, w)
 
@@ -370,30 +439,10 @@ def flims_merge_kv_pallas(a, ra, b, rb, *, w: int = 128,
     arow0, la0 = acut // w, acut % w
     brow0, lb0 = bcut // w, bcut % w
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(G,),
-        in_specs=[
-            element_block_spec(Ha, w,
-                               lambda g, ar0, br0, la, lb: (ar0[g], 0)),
-            element_block_spec(Ha, w,
-                               lambda g, ar0, br0, la, lb: (ar0[g], 0)),
-            element_block_spec(Ha, w,
-                               lambda g, ar0, br0, la, lb: (br0[g], 0)),
-            element_block_spec(Ha, w,
-                               lambda g, ar0, br0, la, lb: (br0[g], 0)),
-        ],
-        out_specs=[pl.BlockSpec((1, C), lambda g, *_: (g, 0)),
-                   pl.BlockSpec((1, C), lambda g, *_: (g, 0))],
-    )
-    kern = functools.partial(_merge_kv_kernel, w=w, cycles=C // w,
-                             descending=descending)
-    ok, orr = pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((G, C), a.dtype),
-                   jax.ShapeDtypeStruct((G, C), jnp.int32)],
-        interpret=interpret,
-        name="flims_merge_kv",
-    )(arow0, brow0, la0, lb0, ak, rak, bk, rbk)
+    ok, orr = merge_call(_merge_kv_kernel, [ak, rak, bk, rbk],
+                         jnp.stack([arow0, brow0, la0, lb0], axis=1),
+                         G=G, C=C, w=w, Ha=Ha,
+                         out_dtypes=[a.dtype, jnp.int32],
+                         name="flims_merge_kv", interpret=interpret,
+                         descending=descending)
     return ok.reshape(-1)[:n_out], orr.reshape(-1)[:n_out]

@@ -45,8 +45,9 @@ from jax.experimental.pallas import tpu as pltpu
 from repro.core.flims import next_pow2 as _next_pow2
 from repro.core.lanes import INVALID_RANK
 from repro.kernels.flims_merge import (_butterfly_desc, _butterfly_kv,
-                                       bound_keys, element_block_spec,
-                                       lane_first)
+                                       bound_keys, fetch_windows, lane_first,
+                                       lane_rev, meta_block, pad_rows,
+                                       row_blocks, row_reader)
 from repro import obs
 
 _RANK_LO = jnp.iinfo(jnp.int32).min
@@ -175,38 +176,40 @@ def _tree_meta_one(grp, o, buf, rbuf, starts, lens, row0, *, group: int,
 # kernel: 2^L - 1 windowed dataflows, inner nodes through scratch streams
 # --------------------------------------------------------------------------
 
-def tree_dataflow(get_rot, leaf_reader, write_chunk, *, w: int, L: int,
-                  C: int, kv: bool, descending: bool, key_dtype,
-                  leaf_rows: int = 0):
+def tree_scratch(*, w: int, L: int, C: int, key_dtype, kv: bool):
+    """VMEM scratch for ``tree_dataflow``'s inner-node streams: one
+    ``(2^L - 2, rows, w)`` buffer per lane, slot ``idx - 1`` for preorder
+    node ``idx`` (the root writes the output instead). A node at depth
+    ``d`` produces ``C/w + d`` rows and is read two fill rows past them."""
+    n_inner = max((1 << L) - 2, 1)
+    rows = pad_rows(C // w + L + 2)
+    dtypes = (key_dtype, jnp.int32) if kv else (key_dtype,)
+    return [pltpu.VMEM((n_inner, rows, w), d) for d in dtypes]
+
+
+def tree_dataflow(get_rot, leaf_reader, write_chunk, acc, *, w: int, L: int,
+                  C: int, kv: bool, descending: bool, key_dtype):
     """The in-kernel nested-dataflow tree, abstracted over storage.
 
     ``2^L - 1`` windowed FLiMS dataflows reduce ``2^L`` leaves to one
-    ``C``-wide output block; inner nodes stream through value-space scratch
-    accumulators, so only the leaves and the root touch refs. Callers
-    supply the storage plumbing:
+    ``C``-wide output block; inner nodes stream through the VMEM scratch
+    ``acc`` (``tree_scratch``: one ref per lane), so only the leaves and
+    the root touch the kernel's inputs and outputs. Callers supply the
+    storage plumbing:
 
     - ``get_rot(idx)`` → the (left, right) initial rotations of preorder
       internal node ``idx`` (from the host nested co-rank partition);
     - ``leaf_reader(j)`` → a ``read(r) -> lanes`` row reader for leaf ``j``
-      (``r`` is a *relative* row; the reader owns clamping/masking);
+      (``r`` is a *relative* row; the reader owns clamping/masking; every
+      lane is a (1, w) row);
     - ``write_chunk(t, chunk)`` stores the root's ``t``-th w-wide chunk.
 
-    ``leaf_rows`` (optional) declares every leaf to hold exactly that many
-    real rows, which lets inner nodes trim their production to the subtree's
-    actual length + one fill chunk instead of the generic ``C/w + depth``
-    cycles. That matters when ``C`` covers the WHOLE group (the fused
-    routing kernel sorts an entire token chunk as one block): without the
-    trim every inner node would stream full-``C`` fills. Reading past a
-    trimmed accumulator clamps to its last (fill) row, which merges
-    identically to explicit fill production.
-
-    Shared by the fused merge-tree kernel (leaves = BlockSpec bank windows),
+    Shared by the fused merge-tree kernel (leaves = DMA'd bank windows) and
     ``kernels/stream_merge.py`` (leaves = double-buffered DMA windows over
-    HBM-resident runs), and ``kernels/route_fuse.py`` (leaves = register-
-    resident bitonic-sorted chunks of one token group).
+    HBM-resident runs).
     """
     group = 1 << L
-    iota = lax.broadcasted_iota(jnp.int32, (w,), 0)
+    iota = lax.broadcasted_iota(jnp.int32, (1, w), 1)
     node_idx = _node_index(group)
     _, last_k = bound_keys(key_dtype, descending)
     if kv:
@@ -221,33 +224,33 @@ def tree_dataflow(get_rot, leaf_reader, write_chunk, *, w: int, L: int,
         fills = (last_k,)
         dtypes = (key_dtype,)
 
-    def acc_reader(acc, nrows):
+    def acc_reader(slot, nrows):
         return lambda r: tuple(
-            lax.dynamic_slice(a, (jnp.minimum(r, nrows - 1) * w,), (w,))
-            for a in acc)
+            a[slot, pl.ds(jnp.minimum(r, nrows - 1), 1), :] for a in acc)
 
     def heads(W0, W1, l):
         return tuple(jnp.where(iota < l, w1, w0) for w0, w1 in zip(W0, W1))
 
-    def merge_stream(read_a, read_b, lA0, lB0, cycles, to_out: bool):
+    def merge_stream(read_a, read_b, lA0, lB0, cycles, slot):
         """One windowed FLiMS dataflow: ``cycles`` w-wide chunks, either into
-        the out refs (root) or into a sentinel-filled scratch stream."""
-        acc0 = () if to_out else tuple(
-            jnp.full(((cycles + 2) * w,), f, d)
-            for f, d in zip(fills, dtypes))
+        the out refs (root, ``slot is None``) or into scratch stream
+        ``slot``, whose two rows past the production hold fill."""
+        if slot is not None:
+            for a, f, d in zip(acc, fills, dtypes):
+                a[slot, pl.ds(cycles, 2), :] = jnp.full((2, w), f, d)
 
         def body(t, carry):
-            WA0, WA1, WB0, WB1, lA, lB, rA, rB, acc = carry
+            WA0, WA1, WB0, WB1, lA, lB, rA, rB = carry
             cA = heads(WA0, WA1, lA)
-            cB = tuple(x[::-1] for x in heads(WB0, WB1, lB))
+            cB = tuple(lane_rev(x) for x in heads(WB0, WB1, lB))
             take = wins(cA, cB)
             chunk = butterfly(tuple(jnp.where(take, xa, xb)
                                     for xa, xb in zip(cA, cB)))
-            if to_out:
+            if slot is None:
                 write_chunk(t, chunk)
             else:
-                acc = tuple(lax.dynamic_update_slice(a, c, (t * w,))
-                            for a, c in zip(acc, chunk))
+                for a, c in zip(acc, chunk):
+                    a[slot, pl.ds(t, 1), :] = c
             k = jnp.sum(take.astype(jnp.int32))
 
             def advance(W0, W1, l, r, read, consumed):
@@ -261,55 +264,56 @@ def tree_dataflow(get_rot, leaf_reader, write_chunk, *, w: int, L: int,
 
             WA0, WA1, lA, rA = advance(WA0, WA1, lA, rA, read_a, k)
             WB0, WB1, lB, rB = advance(WB0, WB1, lB, rB, read_b, w - k)
-            return WA0, WA1, WB0, WB1, lA, lB, rA, rB, acc
+            return WA0, WA1, WB0, WB1, lA, lB, rA, rB
 
         init = (read_a(jnp.int32(0)), read_a(jnp.int32(1)),
                 read_b(jnp.int32(0)), read_b(jnp.int32(1)),
-                lA0, lB0, jnp.int32(2), jnp.int32(2), acc0)
-        return lax.fori_loop(0, cycles, body, init)[-1]
+                lA0, lB0, jnp.int32(2), jnp.int32(2))
+        lax.fori_loop(0, cycles, body, init)
 
     def produce(lo, hi, depth):
-        """Post-order: children first (leaf refs or scratch streams), then
-        this node's dataflow. Root (depth 0) writes the out refs."""
+        """Post-order: children first (leaf windows or scratch streams),
+        then this node's dataflow. Root (depth 0) writes the out refs."""
         mid = (lo + hi) // 2
-        rotL, rotR = get_rot(node_idx[(lo, hi)])
+        idx = node_idx[(lo, hi)]
+        rotL, rotR = get_rot(idx)
         cycles = C // w + depth
-        if leaf_rows and depth > 0:
-            cycles = min(cycles, (hi - lo) * leaf_rows + 1)
 
         def child(clo, chi):
             if chi - clo == 1:
                 return leaf_reader(clo)
-            acc, ccycles = produce(clo, chi, depth + 1)
-            return acc_reader(acc, ccycles + 2)
+            slot, ccycles = produce(clo, chi, depth + 1)
+            return acc_reader(slot, ccycles + 2)
 
-        return (merge_stream(child(lo, mid), child(mid, hi), rotL, rotR,
-                             cycles, to_out=(depth == 0)), cycles)
+        slot = None if depth == 0 else idx - 1
+        merge_stream(child(lo, mid), child(mid, hi), rotL, rotR, cycles, slot)
+        return slot, cycles
 
     produce(0, group, 0)
 
 
-def _tree_kernel(meta_ref, *refs, w: int, L: int, C: int, Ha: int,
+def _tree_kernel(m_ref, *refs, w: int, L: int, C: int, Ha: int,
                  kv: bool, descending: bool):
     group = 1 << L
-    n_in = 2 * group if kv else group
-    ins, outs = refs[:n_in], refs[n_in:]
-    g = pl.program_id(0)
+    nl = 2 if kv else 1
+    banks, outs = refs[:nl], refs[nl:2 * nl]
+    wins, acc, sem = refs[2 * nl:3 * nl], refs[3 * nl:4 * nl], refs[-1]
+    fetch_windows([(banks[li], m_ref[0, 0, j], wins[li].at[j])
+                   for j in range(group) for li in range(nl)], sem)
 
     def leaf_reader(j):
-        lrefs = ins[2 * j:2 * j + 2] if kv else ins[j:j + 1]
-        return lambda r: tuple(ref[jnp.minimum(r, Ha - 1), :]
-                               for ref in lrefs)
+        reads = [row_reader(wins[li].at[j], Ha) for li in range(nl)]
+        return lambda r: tuple(read(r) for read in reads)
 
     def get_rot(idx):
-        return meta_ref[group + 2 * idx, g], meta_ref[group + 2 * idx + 1, g]
+        return m_ref[0, 0, group + 2 * idx], m_ref[0, 0, group + 2 * idx + 1]
 
     def write_chunk(t, chunk):
         for ref, c in zip(outs, chunk):
-            ref[0, pl.ds(t * w, w)] = c
+            ref[0, pl.ds(t, 1), :] = c
 
-    tree_dataflow(get_rot, leaf_reader, write_chunk, w=w, L=L, C=C, kv=kv,
-                  descending=descending, key_dtype=ins[0].dtype)
+    tree_dataflow(get_rot, leaf_reader, write_chunk, acc, w=w, L=L, C=C,
+                  kv=kv, descending=descending, key_dtype=banks[0].dtype)
 
 
 # --------------------------------------------------------------------------
@@ -337,12 +341,13 @@ def _merge_tree_call(buf, ranks, starts, lens, *, group: int, n_out: int,
     C = max(w, min(block_out, _next_pow2(n_out)))
     C = (C // w) * w
     Ha = C // w + L + 2
+    Hp = pad_rows(Ha)                     # rows each leaf window DMAs
 
-    # --- row-aligned banks (one shared bank, one block view per leaf) ------
-    rows_per_run = -(-lens // w) + Ha + 2
+    # --- row-aligned banks (one shared bank, one DMA window per leaf) ------
+    rows_per_run = -(-lens // w) + Hp + 2
     row0 = jnp.concatenate([jnp.zeros((1,), jnp.int32),
                             jnp.cumsum(rows_per_run)]).astype(jnp.int32)
-    ROWS = n_out // w + R * (Ha + 3)
+    ROWS = n_out // w + R * (Hp + 3)
     _, last_k = bound_keys(buf.dtype, descending)
     kbank = _build_bank(buf, starts, lens, row0, ROWS, w, fill=last_k)
     rbank = (_build_bank(ranks.astype(jnp.int32), starts, lens, row0, ROWS,
@@ -362,41 +367,26 @@ def _merge_tree_call(buf, ranks, starts, lens, *, group: int, n_out: int,
     steps = max(1, math.ceil(math.log2(max(n_out, 2))) + 1)
     meta = jax.vmap(lambda gr, oo: _tree_meta_one(
         gr, oo, buf, ranks if kv else None, starts, lens, row0, group=group,
-        w=w, max_row=ROWS - Ha, steps=steps, descending=descending))(grp, o)
-    meta = meta.T.astype(jnp.int32)                       # (n_meta, G)
+        w=w, max_row=ROWS - Hp, steps=steps, descending=descending))(grp, o)
+    mspec, meta = meta_block(meta)                        # (G, 1, n_meta)
 
-    def leaf_spec(j):
-        return element_block_spec(Ha, w, lambda g, m, j=j: (m[j, g], 0))
-
-    if kv:
-        in_specs = [s for j in range(group)
-                    for s in (leaf_spec(j), leaf_spec(j))]
-        inputs = [b for _ in range(group) for b in (kbank, rbank)]
-        out_specs = [pl.BlockSpec((1, C), lambda g, *_: (g, 0)),
-                     pl.BlockSpec((1, C), lambda g, *_: (g, 0))]
-        out_shape = [jax.ShapeDtypeStruct((G, C), buf.dtype),
-                     jax.ShapeDtypeStruct((G, C), jnp.int32)]
-    else:
-        in_specs = [leaf_spec(j) for j in range(group)]
-        inputs = [kbank] * group
-        out_specs = pl.BlockSpec((1, C), lambda g, *_: (g, 0))
-        out_shape = jax.ShapeDtypeStruct((G, C), buf.dtype)
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(G,),
-        in_specs=in_specs,
-        out_specs=out_specs,
-    )
+    banks = [kbank, rbank] if kv else [kbank]
+    spec, _ = row_blocks(G, C, w, buf.dtype)
     kern = functools.partial(_tree_kernel, w=w, L=L, C=C, Ha=Ha, kv=kv,
                              descending=descending)
     out = pl.pallas_call(
         kern,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
+        grid=(G,),
+        in_specs=[mspec] + [pl.BlockSpec(memory_space=pl.ANY)] * len(banks),
+        out_specs=[spec] * len(banks),
+        scratch_shapes=[pltpu.VMEM((group, Hp, w), b.dtype) for b in banks]
+        + tree_scratch(w=w, L=L, C=C, key_dtype=buf.dtype, kv=kv)
+        + [pltpu.SemaphoreType.DMA((group * len(banks),))],
+        out_shape=[row_blocks(G, C, w, b.dtype)[1] for b in banks],
         interpret=interpret,
         name="flims_merge_tree",
-    )(meta, *inputs)
+    )(meta, *banks)
+    out = [o.reshape(G, C) for o in out]
 
     # --- gather padded blocks back to the flat group-order layout ----------
     goff = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(glen)])
@@ -407,7 +397,7 @@ def _merge_tree_call(buf, ranks, starts, lens, *, group: int, n_out: int,
     gg = jnp.clip(blk0[s] + pos // C, 0, G - 1)
     if kv:
         return out[0][gg, pos % C], out[1][gg, pos % C]
-    return out[gg, pos % C]
+    return out[0][gg, pos % C]
 
 
 @functools.partial(jax.jit, static_argnames=("group", "n_out", "w",

@@ -1,8 +1,10 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On CPU (this container) kernels execute with ``interpret=True``; on a real
-TPU backend they compile through Mosaic. ``kernel_sort`` is the end-to-end
-two-level sorter: Pallas chunk sort + partitioned Pallas FLiMS merge passes.
+Every wrapper takes ``interpret`` from its caller; ``None`` asks the one
+backend predicate, ``engine.schedule.default_interpret`` (Mosaic on a TPU
+backend, the Pallas interpreter elsewhere). ``kernel_sort`` is the
+end-to-end two-level sorter: Pallas chunk sort + fused FLiMS merge-tree
+passes.
 """
 from __future__ import annotations
 
@@ -19,28 +21,31 @@ from repro.kernels.flims_merge import bound_keys, flims_merge_pallas
 from repro import obs
 
 
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
+def _interpret(interpret):
+    from repro.engine.schedule import default_interpret
+    return default_interpret() if interpret is None else interpret
 
 
 def merge(a: jnp.ndarray, b: jnp.ndarray, *, w: int = 128,
-          block_out: int = 4096) -> jnp.ndarray:
+          block_out: int = 4096, interpret=None) -> jnp.ndarray:
     """Descending merge of two sorted 1-D arrays (Pallas FLiMS kernel)."""
     return flims_merge_pallas(a, b, w=w, block_out=block_out,
-                              interpret=not _on_tpu())
+                              interpret=_interpret(interpret))
 
 
-def sort_rows(x: jnp.ndarray, *, rows_per_block: int = 8) -> jnp.ndarray:
+def sort_rows(x: jnp.ndarray, *, rows_per_block: int = 8,
+              interpret=None) -> jnp.ndarray:
     """Descending per-row sort of an (m, c) array (Pallas bitonic kernel)."""
     return sort_chunks_pallas(x, rows_per_block=rows_per_block,
-                              interpret=not _on_tpu())
+                              interpret=_interpret(interpret))
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "w", "descending",
-                                             "levels"))
+                                             "levels", "interpret"))
 @obs.scoped("kernels.kernel_sort")
 def kernel_sort(x: jnp.ndarray, *, chunk: int = 512, w: int = 128,
-                descending: bool = True, levels: int = 2) -> jnp.ndarray:
+                descending: bool = True, levels: int = 2,
+                interpret=None) -> jnp.ndarray:
     """Full sort of a 1-D array: chunk kernel + fused FLiMS merge-tree passes.
 
     The merge phase executes a ``tree_pallas`` MergeSchedule (DESIGN.md §5):
@@ -48,6 +53,7 @@ def kernel_sort(x: jnp.ndarray, *, chunk: int = 512, w: int = 128,
     the intermediate runs resident in kernel scratch instead of HBM.
     """
     from repro.engine.schedule import MergeSchedule, reduce_rows
+    interpret = _interpret(interpret)
     n = x.shape[0]
     if n <= 1:
         return x
@@ -62,11 +68,11 @@ def kernel_sort(x: jnp.ndarray, *, chunk: int = 512, w: int = 128,
         m2 *= 2
     n_pad = m2 * c
     xp = jnp.pad(x, (0, n_pad - n), constant_values=sentinel_for(x.dtype))
-    rows = sort_rows(xp.reshape(-1, c))
+    rows = sort_chunks_pallas(xp.reshape(-1, c), interpret=interpret)
     ww = min(w, c)
     sched = MergeSchedule("tree_pallas", levels_per_pass=levels, w=ww,
                           block_out=max(ww, 4096))
-    merged = reduce_rows(rows, schedule=sched, interpret=not _on_tpu())
+    merged = reduce_rows(rows, schedule=sched, interpret=interpret)
     out = merged[:n]
     return out if descending else out[::-1]
 
@@ -75,8 +81,8 @@ def kernel_sort(x: jnp.ndarray, *, chunk: int = 512, w: int = 128,
                                              "interpret", "levels"))
 @obs.scoped("kernels.kernel_argsort")
 def kernel_argsort(keys: jnp.ndarray, *, chunk: int = 256, w: int = 32,
-                   descending: bool = True, interpret: bool = None,
-                   levels: int = 2) -> jnp.ndarray:
+                   descending: bool = True, levels: int = 2,
+                   interpret=None) -> jnp.ndarray:
     """Stable argsort of a 1-D array, entirely in Pallas KV kernels.
 
     The two-level sorter of ``kernel_sort`` over (key, rank) lanes: one KV
@@ -88,8 +94,7 @@ def kernel_argsort(keys: jnp.ndarray, *, chunk: int = 256, w: int = 32,
     not by mirroring).
     """
     from repro.engine.schedule import MergeSchedule, reduce_rows
-    if interpret is None:
-        interpret = not _on_tpu()
+    interpret = _interpret(interpret)
     n = keys.shape[0]
     if n <= 1:
         return jnp.zeros((n,), jnp.int32)

@@ -3,8 +3,8 @@
 Extends the merge-path partitioning of ``kernels/flims_merge.py`` (DESIGN.md
 §2) from one merge to a whole *ragged batch* of merges in a single
 ``pallas_call``: the grid is flattened over (segment, output-block) pairs and
-four scalar-prefetched vectors carry, per grid step, the co-rank row/rotation
-of each input run. Because every output block is ``C`` elements with ``C`` a
+one SMEM row per grid step carries the co-rank row/rotation of each input
+run. Because every output block is ``C`` elements with ``C`` a
 multiple of ``w``, the FLiMS rotation invariant ``(lA + lB) ≡ 0 (mod w)``
 holds at every (segment, block) boundary, so each grid step starts the banked
 dataflow mid-rotation with zero realignment — the same property the
@@ -29,15 +29,14 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.flims import sentinel_for, next_pow2 as _next_pow2
 from repro.core.lanes import INVALID_RANK
 from repro.kernels.bitonic_sort import (_bitonic_rows_desc, _sort_kv_kernel,
                                         sort_chunks_kv_pallas)
 from repro.kernels.flims_merge import (_merge_kernel, _merge_kv_kernel,
-                                       bound_keys, element_block_spec,
-                                       lane_first, plus_inf_for)
+                                       bound_keys, lane_first, merge_call,
+                                       pad_rows, plus_inf_for)
 from repro import obs
 
 
@@ -155,6 +154,7 @@ def segmented_merge_runs(a, b, a_starts, a_lens, b_starts, b_lens, *,
     C = (C // w) * w
     cycles = C // w
     Ha = cycles + 2
+    Hp = pad_rows(Ha)                     # rows each step's window DMAs
     G = n_out // C + R                    # >= sum ceil(out_len_s / C)
 
     a_starts = a_starts.astype(jnp.int32)
@@ -173,11 +173,11 @@ def segmented_merge_runs(a, b, a_starts, a_lens, b_starts, b_lens, *,
     o = jnp.minimum((g - blk0[seg]) * C, (lo_len[seg] // C) * C)
 
     # --- per-run row-aligned banks -----------------------------------------
-    ra = -(-la // w) + Ha + 2
-    rb = -(-lb // w) + Ha + 2
+    ra = -(-la // w) + Hp + 2
+    rb = -(-lb // w) + Hp + 2
     ra0 = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(ra)])
     rb0 = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(rb)])
-    RA = n_out // w + R * (Ha + 3)                    # static row capacity
+    RA = n_out // w + R * (Hp + 3)                    # static row capacity
     RB = RA
     abank = _build_bank(a, a_starts, la, ra0, RA, w)
     bbank = _build_bank(b, b_starts, lb, rb0, RB, w)
@@ -188,30 +188,16 @@ def segmented_merge_runs(a, b, a_starts, a_lens, b_starts, b_lens, *,
         oo, la[s], lb[s], a_starts[s], b_starts[s], a, b, steps))(o, seg)
     acut = acut.astype(jnp.int32)
     bcut = o - acut
-    arow0 = jnp.minimum(ra0[seg] + acut // w, RA - Ha)
-    brow0 = jnp.minimum(rb0[seg] + bcut // w, RB - Ha)
+    arow0 = jnp.minimum(ra0[seg] + acut // w, RA - Hp)
+    brow0 = jnp.minimum(rb0[seg] + bcut // w, RB - Hp)
     la0 = acut % w
     lb0 = bcut % w
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(G,),
-        in_specs=[
-            element_block_spec(Ha, w,
-                               lambda g, ar0, br0, la, lb: (ar0[g], 0)),
-            element_block_spec(Ha, w,
-                               lambda g, ar0, br0, la, lb: (br0[g], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, C), lambda g, *_: (g, 0)),
-    )
-    kern = functools.partial(_merge_kernel, w=w, cycles=cycles)
-    out = pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((G, C), a.dtype),
-        interpret=interpret,
-        name="flims_segmented_merge",
-    )(arow0, brow0, la0, lb0, abank, bbank)
+    (out,) = merge_call(_merge_kernel, [abank, bbank],
+                        jnp.stack([arow0, brow0, la0, lb0], axis=1),
+                        G=G, C=C, w=w, Ha=Ha,
+                        out_dtypes=[a.dtype], name="flims_segmented_merge",
+                        interpret=interpret)
 
     # --- gather padded blocks back to the flat ragged layout ---------------
     oo = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(lo_len)])
@@ -302,7 +288,7 @@ def segmented_merge_runs_kv(a, ra, b, rb, a_starts, a_lens, b_starts, b_lens,
     """Stable KV variant of ``segmented_merge_runs``: merge R run pairs of
     (key, rank) lanes in ONE ``pallas_call``. Returns (keys, ranks).
 
-    Same flat (segment, block) grid, scalar-prefetched co-ranks, and bank
+    Same flat (segment, block) grid, per-step co-ranks, and bank
     layout as the keys-only kernel — the co-rank partition is
     payload-oblivious, so the only change is one extra int32 bank per side
     and the compound comparator end-to-end.
@@ -317,6 +303,7 @@ def segmented_merge_runs_kv(a, ra, b, rb, a_starts, a_lens, b_starts, b_lens,
     C = (C // w) * w
     cycles = C // w
     Ha = cycles + 2
+    Hp = pad_rows(Ha)
     G = n_out // C + R
 
     a_starts = a_starts.astype(jnp.int32)
@@ -331,11 +318,11 @@ def segmented_merge_runs_kv(a, ra, b, rb, a_starts, a_lens, b_starts, b_lens,
     seg = jnp.clip(jnp.searchsorted(blk0, g, side="right") - 1, 0, R - 1)
     o = jnp.minimum((g - blk0[seg]) * C, (lo_len[seg] // C) * C)
 
-    rra = -(-la // w) + Ha + 2
-    rrb = -(-lb // w) + Ha + 2
+    rra = -(-la // w) + Hp + 2
+    rrb = -(-lb // w) + Hp + 2
     ra0 = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(rra)])
     rb0 = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(rrb)])
-    RA = n_out // w + R * (Ha + 3)
+    RA = n_out // w + R * (Hp + 3)
     RB = RA
     _, lastK = bound_keys(a.dtype, descending)
     abank = _build_bank(a, a_starts, la, ra0, RA, w, fill=lastK)
@@ -349,37 +336,17 @@ def segmented_merge_runs_kv(a, ra, b, rb, a_starts, a_lens, b_starts, b_lens,
         descending))(o, seg)
     acut = acut.astype(jnp.int32)
     bcut = o - acut
-    arow0 = jnp.minimum(ra0[seg] + acut // w, RA - Ha)
-    brow0 = jnp.minimum(rb0[seg] + bcut // w, RB - Ha)
+    arow0 = jnp.minimum(ra0[seg] + acut // w, RA - Hp)
+    brow0 = jnp.minimum(rb0[seg] + bcut // w, RB - Hp)
     la0 = acut % w
     lb0 = bcut % w
 
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=4,
-        grid=(G,),
-        in_specs=[
-            element_block_spec(Ha, w,
-                               lambda g, ar0, br0, la, lb: (ar0[g], 0)),
-            element_block_spec(Ha, w,
-                               lambda g, ar0, br0, la, lb: (ar0[g], 0)),
-            element_block_spec(Ha, w,
-                               lambda g, ar0, br0, la, lb: (br0[g], 0)),
-            element_block_spec(Ha, w,
-                               lambda g, ar0, br0, la, lb: (br0[g], 0)),
-        ],
-        out_specs=[pl.BlockSpec((1, C), lambda g, *_: (g, 0)),
-                   pl.BlockSpec((1, C), lambda g, *_: (g, 0))],
-    )
-    kern = functools.partial(_merge_kv_kernel, w=w, cycles=cycles,
-                             descending=descending)
-    ok, orr = pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct((G, C), a.dtype),
-                   jax.ShapeDtypeStruct((G, C), jnp.int32)],
-        interpret=interpret,
-        name="flims_segmented_merge_kv",
-    )(arow0, brow0, la0, lb0, abank, arbank, bbank, brbank)
+    ok, orr = merge_call(_merge_kv_kernel, [abank, arbank, bbank, brbank],
+                         jnp.stack([arow0, brow0, la0, lb0], axis=1),
+                         G=G, C=C, w=w, Ha=Ha,
+                         out_dtypes=[a.dtype, jnp.int32],
+                         name="flims_segmented_merge_kv",
+                         interpret=interpret, descending=descending)
 
     oo = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(lo_len)])
     i = jnp.arange(n_out, dtype=jnp.int32)

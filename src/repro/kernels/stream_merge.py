@@ -8,7 +8,7 @@ step pulls only the windows its output block can touch:
 
 - Input is one flat buffer of ``runs`` uniform sorted runs of ``run_len``
   elements (``run_len`` a power of two multiple of ``w``), viewed as a
-  ``(ROWS, w)`` array in ``pltpu.TPUMemorySpace.ANY`` — never mapped to
+  ``(ROWS, w)`` array in ``pl.ANY`` memory — never mapped to
   scratch by a BlockSpec.
 - Consecutive ``fan_in = 2^L`` runs form one group; the grid flattens
   (group, output-block) pairs exactly like the fused tree kernel, and the
@@ -26,7 +26,7 @@ step pulls only the windows its output block can touch:
   DMA stays in bounds) and the leaf reader masks rows past ``run_rows``
   to sentinel lanes in-register.
 
-The output is written in ``(G, C)`` blocks and returned flat with the same
+The output is written in ``C``-key blocks and returned flat with the same
 trailing-slack contract as the input, so phase-2 passes chain without a
 re-pack: each pass costs exactly one read + one write of the data —
 ``ceil(log_fan_in(runs))`` HBM round trips total.
@@ -44,9 +44,13 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.flims import next_pow2 as _next_pow2
 from repro.core.lanes import INVALID_RANK
-from repro.kernels.flims_merge import bound_keys
-from repro.kernels.merge_tree import _tree_fns, tree_dataflow
+from repro.kernels.flims_merge import bound_keys, pad_rows, row_blocks
+from repro.kernels.merge_tree import _tree_fns, tree_dataflow, tree_scratch
 from repro import obs
+
+
+#: grid steps per ``pallas_call``: bounds the scalar-prefetched meta slice
+_CALL_STEPS = 4096
 
 
 def _block(block_out: int, run_len: int, fan_in: int, w: int) -> int:
@@ -60,10 +64,11 @@ def _block(block_out: int, run_len: int, fan_in: int, w: int) -> int:
 def stream_slack(fan_in: int, w: int, block_out: int) -> int:
     """Trailing elements a buffer must carry beyond the live data so every
     leaf DMA window stays in bounds: the deepest window is ``Ha`` rows
-    (``C//w + L + 2``) and the worst-case start is the run's end."""
+    (``C//w + L + 2``, DMA'd as a whole sublane tile) and the worst-case
+    start is the run's end."""
     L = max(fan_in.bit_length() - 1, 1)
     C = 1 << (max(w, block_out).bit_length() - 1)
-    return (C // w + L + 2) * w
+    return pad_rows(C // w + L + 2) * w
 
 
 def _stream_meta_one(grp, o, buf, rbuf, *, runs: int, run_len: int,
@@ -99,26 +104,29 @@ def _stream_meta_one(grp, o, buf, rbuf, *, runs: int, run_len: int,
 
 
 def _stream_kernel(meta_ref, *refs, w: int, L: int, C: int, Ha: int,
-                   bpg: int, run_rows: int, G: int, kv: bool,
+                   bpg: int, run_rows: int, G: int, g0: int, kv: bool,
                    descending: bool):
     group = 1 << L
-    nlanes = 2 if kv else 1
-    hbm = refs[:nlanes]                       # ANY-space (ROWS, w) views
-    outs = refs[nlanes:2 * nlanes]            # (1, C) output blocks
-    bufs = refs[2 * nlanes:2 * nlanes + nlanes]   # VMEM (2, group, Ha, w)
-    sems = refs[-1]                           # DMA sems (2, group, nlanes)
+    nl = 2 if kv else 1
+    hbm = refs[:nl]                           # ANY-space (ROWS, w) views
+    # refs[nl:2 * nl] are the output buffers so far (aliased to ``outs``)
+    outs = refs[2 * nl:3 * nl]                # (1, C/w, w) output blocks
+    bufs = refs[3 * nl:4 * nl]                # VMEM (2, group, Hp, w)
+    acc = refs[4 * nl:5 * nl]                 # inner-node streams
+    sems = refs[-1]                           # DMA sems (2, group, nl)
     g = pl.program_id(0)
     slot = lax.rem(g, 2)
 
     def dma(step, sl, j, li):
-        row = (step // bpg * group + j) * run_rows + meta_ref[j, step]
+        grp = (g0 + step) // bpg
+        row = (grp * group + j) * run_rows + meta_ref[j, step]
+        dst = bufs[li].at[sl, j]
         return pltpu.make_async_copy(
-            hbm[li].at[pl.ds(row, Ha)], bufs[li].at[sl, j],
-            sems.at[sl, j, li])
+            hbm[li].at[pl.ds(row, dst.shape[0])], dst, sems.at[sl, j, li])
 
     def start_all(step, sl):
         for j in range(group):
-            for li in range(nlanes):
+            for li in range(nl):
                 dma(step, sl, j, li).start()
 
     @pl.when(g == 0)
@@ -130,7 +138,7 @@ def _stream_kernel(meta_ref, *refs, w: int, L: int, C: int, Ha: int,
         start_all(g + 1, 1 - slot)            # prefetch the next block
 
     for j in range(group):
-        for li in range(nlanes):
+        for li in range(nl):
             dma(g, slot, j, li).wait()
 
     key_dtype = hbm[0].dtype
@@ -143,8 +151,9 @@ def _stream_kernel(meta_ref, *refs, w: int, L: int, C: int, Ha: int,
         def read(r):
             row = jnp.minimum(r, Ha - 1)
             valid = srow + r < run_rows       # rows past the run are pads
-            return tuple(jnp.where(valid, bufs[li][slot, j, row, :], f)
-                         for li, f in enumerate(fills))
+            return tuple(
+                jnp.where(valid, bufs[li][slot, j, pl.ds(row, 1), :], f)
+                for li, f in enumerate(fills))
 
         return read
 
@@ -153,10 +162,10 @@ def _stream_kernel(meta_ref, *refs, w: int, L: int, C: int, Ha: int,
 
     def write_chunk(t, chunk):
         for ref, c in zip(outs, chunk):
-            ref[0, pl.ds(t * w, w)] = c
+            ref[0, pl.ds(t, 1), :] = c
 
-    tree_dataflow(get_rot, leaf_reader, write_chunk, w=w, L=L, C=C, kv=kv,
-                  descending=descending, key_dtype=key_dtype)
+    tree_dataflow(get_rot, leaf_reader, write_chunk, acc, w=w, L=L, C=C,
+                  kv=kv, descending=descending, key_dtype=key_dtype)
 
 
 def _stream_call(buf, ranks, *, runs: int, run_len: int, fan_in: int,
@@ -203,38 +212,44 @@ def _stream_call(buf, ranks, *, runs: int, run_len: int, fan_in: int,
 
     # --- one pallas_call: sequential grid, cross-step double buffering ----
     out_blocks = -(-(n_val + out_slack) // C)
-    any_spec = pl.BlockSpec(memory_space=pltpu.TPUMemorySpace.ANY)
-    out_spec = pl.BlockSpec((1, C), lambda g, m: (g, 0))
-    scratch = [pltpu.VMEM((2, 1 << L, Ha, w), d)
-               for d in ((buf.dtype, jnp.int32) if kv else (buf.dtype,))]
-    scratch.append(pltpu.SemaphoreType.DMA((2, 1 << L, 2 if kv else 1)))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(G,),
-        in_specs=[any_spec] * (2 if kv else 1),
-        out_specs=[out_spec] * (2 if kv else 1) if kv else out_spec,
-        scratch_shapes=scratch,
-    )
-    kern = functools.partial(_stream_kernel, w=w, L=L, C=C, Ha=Ha, bpg=bpg,
-                             run_rows=run_rows, G=G, kv=kv,
-                             descending=descending)
-    out_shape = jax.ShapeDtypeStruct((out_blocks, C), buf.dtype)
-    if kv:
-        out_shape = [out_shape, jax.ShapeDtypeStruct((out_blocks, C),
-                                                     jnp.int32)]
-    out = pl.pallas_call(
-        kern,
-        grid_spec=grid_spec,
-        out_shape=out_shape,
-        interpret=interpret,
-        name="flims_stream_merge",
-    )(meta, *((kview, rview) if kv else (kview,)))
+    dtypes = (buf.dtype, jnp.int32) if kv else (buf.dtype,)
+    scratch = [pltpu.VMEM((2, 1 << L, pad_rows(Ha), w), d) for d in dtypes]
+    scratch += tree_scratch(w=w, L=L, C=C, key_dtype=buf.dtype, kv=kv)
+    scratch.append(pltpu.SemaphoreType.DMA((2, 1 << L, len(dtypes))))
+    nl = len(dtypes)
+    out = [jnp.zeros(row_blocks(out_blocks, C, w, d)[1].shape, d)
+           for d in dtypes]
+    # the grid runs in calls of at most _CALL_STEPS steps: each scalar-
+    # prefetches only its own slice of the meta table (SMEM is 1 MiB) and
+    # writes its blocks in place into the one output buffer
+    for g0 in range(0, G, _CALL_STEPS):
+        Gc = min(_CALL_STEPS, G - g0)
+        out_spec = pl.BlockSpec((1, C // w, w),
+                                lambda g, m, g0=g0: (g + g0, 0, 0))
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(Gc,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)] * (2 * nl),
+            out_specs=[out_spec] * nl,
+            scratch_shapes=scratch,
+        )
+        kern = functools.partial(_stream_kernel, w=w, L=L, C=C, Ha=Ha,
+                                 bpg=bpg, run_rows=run_rows, G=Gc, g0=g0,
+                                 kv=kv, descending=descending)
+        out = pl.pallas_call(
+            kern,
+            grid_spec=grid_spec,
+            out_shape=[row_blocks(out_blocks, C, w, d)[1] for d in dtypes],
+            input_output_aliases={1 + nl + i: i for i in range(nl)},
+            interpret=interpret,
+            name="flims_stream_merge",
+        )(meta[:, g0:g0 + Gc], *((kview, rview) if kv else (kview,)), *out)
 
     # Blocks past G are never written; the caller only reads [:n_val] and
     # the kernel only reads windows inside [0, n_val + slack).
     if kv:
         return out[0].reshape(-1), out[1].reshape(-1)
-    return out.reshape(-1)
+    return out[0].reshape(-1)
 
 
 @functools.partial(jax.jit, static_argnames=("runs", "run_len", "fan_in",

@@ -1,31 +1,60 @@
 """Roofline-term extraction from compiled XLA artifacts.
 
-Hardware model (TPU v5e, per assignment):
-  peak bf16 compute : 197 TFLOP/s per chip
-  HBM bandwidth     : 819 GB/s per chip
-  ICI link bandwidth: ~50 GB/s per link
+Every peak comes from one table keyed by the device kind JAX reports
+(``jax.devices()[0].device_kind``), each row with its published source; a
+device that is not in the table has no roofline and asking for one raises.
 
 terms (seconds, per device — the SPMD module is the per-device program):
   compute    = HLO_FLOPs / peak
-  memory     = HLO_bytes / bw
-  collective = collective_operand_bytes / link_bw
+  memory     = HLO_bytes / HBM bandwidth
+  collective = collective_operand_bytes / per-link ICI bandwidth
 """
 from __future__ import annotations
 
 import re
 from dataclasses import dataclass, asdict
-from typing import Dict, Optional
+from typing import Dict, NamedTuple, Optional
 
-PEAK_FLOPS = 197e12
-HBM_BW = 819e9
-LINK_BW = 50e9
 
-#: main-memory streaming bandwidth per backend (bytes/s) — the roofline
-#: ceiling benchmark rows are reported against. TPU v5e HBM per the hardware
-#: model above; the CPU/GPU figures are coarse container-class estimates
-#: (dual-channel DDR host, A100-class HBM2e) so off-TPU rows still carry a
-#: meaningful achieved-vs-peak fraction.
-MEM_BW_BY_BACKEND = {"tpu": HBM_BW, "gpu": 1.6e12, "cpu": 40e9}
+class Peaks(NamedTuple):
+    flops: float       # bf16 FLOP/s per chip
+    hbm_bw: float      # HBM bytes/s per chip
+    link_bw: float     # ICI bytes/s per link
+    source: str
+
+
+#: published per-chip peaks, keyed by ``Device.device_kind``
+PEAKS = {
+    "TPU v5 lite": Peaks(
+        flops=197e12, hbm_bw=819e9, link_bw=50e9,
+        source="Google Cloud documentation, 'TPU v5e': 197 TFLOP/s bf16, "
+               "16 GB HBM at 819 GB/s, 1,600 Gbit/s ICI per chip (4 links)"),
+}
+
+#: the chip the dry-run compiles for (``launch/dryrun.py``)
+V5E = "TPU v5 lite"
+
+
+def device_kind() -> str:
+    import jax
+    return jax.devices()[0].device_kind
+
+
+def peaks(kind: Optional[str] = None) -> Peaks:
+    """The published peaks of ``kind`` (the first device's by default)."""
+    kind = kind or device_kind()
+    try:
+        return PEAKS[kind]
+    except KeyError:
+        raise ValueError(f"no published peaks for device kind {kind!r}; "
+                         f"known: {sorted(PEAKS)}") from None
+
+
+def mem_bw() -> float:
+    """HBM bandwidth (bytes/s) of the first device — the denominator of
+    every achieved-GB/s fraction the benchmarks report."""
+    return peaks().hbm_bw
+
 
 _DTYPE_BYTES = {
     "pred": 1, "s8": 1, "u8": 1, "s16": 2, "u16": 2, "bf16": 2, "f16": 2,
@@ -38,25 +67,6 @@ _COLLECTIVES = ("all-reduce", "all-gather", "reduce-scatter", "all-to-all",
 
 _SHAPE_RE = re.compile(r"\b(pred|bf16|f16|f32|f64|f8e4m3fn|f8e5m2|"
                        r"[su](?:4|8|16|32|64)|c64|c128)\[([\d,]*)\]")
-
-
-def mem_bw(backend: Optional[str] = None) -> float:
-    """Streaming-memory bandwidth ceiling (bytes/s) for a backend (the
-    current jax backend by default) — the denominator of every achieved-GB/s
-    fraction the benchmarks report.
-
-    The per-backend table is a coarse class estimate; on containers that
-    don't match it, set ``REPRO_MEM_BW_GBPS`` (GB/s, decimal) to the
-    measured machine bandwidth so achieved-vs-peak fractions stay
-    meaningful."""
-    import os
-    env = os.environ.get("REPRO_MEM_BW_GBPS")
-    if env:
-        return float(env) * 1e9
-    if backend is None:
-        import jax
-        backend = jax.default_backend()
-    return MEM_BW_BY_BACKEND.get(backend, HBM_BW)
 
 
 # --------------------------------------------------------------------------
@@ -147,12 +157,6 @@ def moe_dispatch_bytes(T: int, E: int, k: int, d: int, cap: int,
     return io + 4 * slab + moe_route_bytes(T, E, k, fused)
 
 
-def bound_us(n_bytes: float, backend: Optional[str] = None) -> float:
-    """Roofline lower bound (µs) for moving ``n_bytes`` at the backend's
-    streaming bandwidth."""
-    return n_bytes / mem_bw(backend) * 1e6
-
-
 def _shape_bytes(dtype: str, dims: str) -> int:
     n = 1
     if dims:
@@ -194,9 +198,10 @@ def analyse(compiled, *, model_flops: float, n_chips: int) -> Roofline:
     flops = cost.flops
     hbm = cost.bytes
     coll = cost.coll_total
-    t_c = flops / PEAK_FLOPS
-    t_m = hbm / HBM_BW
-    t_x = coll / LINK_BW
+    pk = peaks(V5E)
+    t_c = flops / pk.flops
+    t_m = hbm / pk.hbm_bw
+    t_x = coll / pk.link_bw
     terms = {"compute": t_c, "memory": t_m, "collective": t_x}
     bottleneck = max(terms, key=terms.get)
     mem = compiled.memory_analysis()

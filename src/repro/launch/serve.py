@@ -160,6 +160,8 @@ def main(argv=None):
                          "timer histogram, tok/s, occupancy, trace count), "
                          "plus a final obs report")
     args = ap.parse_args(argv)
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     cfg = get_config(args.arch)
     if args.reduced:
         cfg = cfg.reduced()

@@ -50,12 +50,18 @@ class TestRow:
                        "us_per_call": 5.0, "derived": {"k": 1}}
         json.dumps(rec)                      # JSON-clean
 
-    def test_bw_fields_roofline_columns(self):
-        f = bw_fields(40_000_000, 1000.0)    # 40 MB in 1 ms -> 40 GB/s
+    def test_bw_fields_roofline_columns(self, monkeypatch):
+        from repro.launch import roofline
+        # 40 MB in 1 ms -> 40 GB/s, against the v5e's published 819 GB/s
+        monkeypatch.setattr(roofline, "device_kind", lambda: "TPU v5 lite")
+        f = bw_fields(40_000_000, 1000.0)
         assert f["gbps"] == pytest.approx(40.0)
-        assert f["roof_gbps"] > 0
+        assert f["roof_gbps"] == pytest.approx(819.0)
         assert f["roof_frac"] == pytest.approx(f["gbps"] / f["roof_gbps"],
                                                abs=1e-3)
+        # a device without published peaks (a CPU) gets no roofline
+        monkeypatch.setattr(roofline, "device_kind", lambda: "cpu")
+        assert bw_fields(40_000_000, 1000.0) == {"gbps": 40.0}
 
     def test_env_metadata_fields(self):
         meta = env_metadata("2026-01-01T00:00:00")

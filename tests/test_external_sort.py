@@ -7,7 +7,7 @@ misalignment, heavy ties; the edge contracts (single-tile delegation,
 fan-in larger than the run count, int32 lane guard); the observable
 ``ceil(log_fan_in(runs))`` pass-count claim; the streaming kernel and the
 ``stream_xla``/``stream_pallas`` MergeSchedule executors directly; and the
-roofline traffic model + ``REPRO_MEM_BW_GBPS`` override satellites.
+roofline traffic model and the peaks table keyed by device kind.
 """
 import numpy as np
 import jax
@@ -326,12 +326,10 @@ def test_external_traffic_model():
         2 * 16 * 1024 * 4 * 3
 
 
-def test_mem_bw_env_override(monkeypatch):
+def test_mem_bw_by_device_kind(monkeypatch):
     from repro.launch import roofline
-    monkeypatch.delenv("REPRO_MEM_BW_GBPS", raising=False)
-    base = roofline.mem_bw("cpu")
-    monkeypatch.setenv("REPRO_MEM_BW_GBPS", "123.5")
-    assert roofline.mem_bw("cpu") == 123.5e9
-    assert roofline.mem_bw("tpu") == 123.5e9   # override beats the table
-    monkeypatch.delenv("REPRO_MEM_BW_GBPS")
-    assert roofline.mem_bw("cpu") == base
+    monkeypatch.setattr(roofline, "device_kind", lambda: "TPU v5 lite")
+    assert roofline.mem_bw() == 819e9
+    monkeypatch.setattr(roofline, "device_kind", lambda: "cpu")
+    with pytest.raises(ValueError, match="no published peaks"):
+        roofline.mem_bw()

@@ -69,16 +69,14 @@ class TestFusedVsReference:
     def test_bit_for_bit(self, G, T, E, k, cap, tied):
         lg = _logits(G, T, E, seed=G * T + E + k, tied=tied)
         ref = moe_route_xla(lg, k, cap)
-        for chunk in (64, 256):
-            got = moe_route_pallas(lg, k, cap, chunk=chunk)
-            for name, a, b in zip(
-                    ("experts", "tokens", "perm", "weights", "slabs",
-                     "keep"), got, ref):
-                np.testing.assert_array_equal(
-                    np.asarray(a), np.asarray(b),
-                    err_msg=f"{name} lane, chunk={chunk}")
+        got = moe_route_pallas(lg, k, cap)
+        for name, a, b in zip(
+                ("experts", "tokens", "perm", "weights", "slabs",
+                 "keep"), got, ref):
+            np.testing.assert_array_equal(np.asarray(a), np.asarray(b),
+                                          err_msg=f"{name} lane")
 
-    @pytest.mark.parametrize("G,T,E,k,cap", SHAPES[:4])
+    @pytest.mark.parametrize("G,T,E,k,cap", SHAPES)
     def test_matches_frozen_legacy_pipeline(self, G, T, E, k, cap):
         lg = _logits(G, T, E, seed=3)
         legacy = _legacy_route(lg, k, cap)
@@ -213,7 +211,8 @@ class TestEngineOp:
         from repro.engine.planner import candidate_plans
         cands = candidate_plans("moe_route", key)
         assert {c.variant for c in cands} == {"fused", "xla"}
-        assert len([c for c in cands if c.variant == "fused"]) >= 2
+        # the fused kernel sorts a whole group in one network: no tile dof
+        assert len([c for c in cands if c.variant == "fused"]) == 1
         plan = engine.autotune("moe_route", lg, 2, 10)
         assert plan.variant in ("fused", "xla")
         # the tuned plan is installed and serves subsequent calls

@@ -60,6 +60,21 @@ def test_flims_topk_batched():
     np.testing.assert_array_equal(np.array(i), np.array(ei))
 
 
+@pytest.mark.parametrize("shape,k", [((3 << 18) + 5, 64), ((2, 1 << 19), 8)])
+def test_flims_topk_chunked(shape, k):
+    """Rows wider than one network chunk reduce chunk by chunk: the result
+    (and the payload riding it) is still ``lax.top_k``'s, ties included."""
+    from repro.core.topk import CHUNK
+    x = np.random.default_rng(1).integers(-50, 50, shape).astype(np.int32)
+    assert x.shape[-1] > CHUNK
+    pos = np.broadcast_to(np.arange(x.shape[-1], dtype=np.int32), x.shape)
+    v, i, p = flims_topk(jnp.array(x), k, values=jnp.array(pos))
+    ev, ei = jax.lax.top_k(jnp.array(x), k)
+    np.testing.assert_array_equal(np.array(v), np.array(ev))
+    np.testing.assert_array_equal(np.array(i), np.array(ei))
+    np.testing.assert_array_equal(np.array(p), np.array(ei))
+
+
 @pytest.mark.parametrize("K,n", [(2, 64), (8, 128), (16, 32)])
 def test_pmt_merge(K, n):
     rng = np.random.default_rng(K)
