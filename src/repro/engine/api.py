@@ -34,6 +34,7 @@ and ``argsort`` / ``segment_argsort`` return the stable permutation itself.
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Optional
 
 import jax
@@ -71,6 +72,28 @@ def _gcall(op: str, plan: Plan, *args, **kw):
     the candidate order with quarantine; input errors propagate."""
     from repro.guard.fallback import guarded_call
     return guarded_call(op, plan, *args, **kw)
+
+
+def _public(fn):
+    """A public op: its whole call runs under the ``engine.<op>`` span, the
+    root of the call's span tree (DESIGN.md §7)."""
+    name = f"engine.{fn.__name__}"
+
+    @functools.wraps(fn)
+    def call(*args, **kw):
+        with obs.span(name):
+            return fn(*args, **kw)
+    return call
+
+
+def _validated(op: str, keys, nan: Optional[str], width=None):
+    """The boundary checks of one op under the ``engine.validate`` span:
+    the int32 lane width (when ``width`` is given), then the NaN policy;
+    returns what :func:`_nan_keys` returns."""
+    with obs.span("engine.validate"):
+        if width is not None:
+            _check_lane_width(width, op)
+        return _nan_keys(op, keys, nan)
 
 
 def _nan_keys(op: str, keys, nan: Optional[str]):
@@ -131,25 +154,26 @@ def infer_key(op: str, *args):
 
 def _resolve(op: str, plan: Optional[Plan], variant: Optional[str], *args,
              **key_extra) -> Plan:
-    if plan is None:
-        key = infer_key(op, *args)
-        plan = default_planner.lookup(key)
+    with obs.span("engine.plan"):
         if plan is None:
-            plan = heuristic_plan(op, key)
-            obs.inc("plan_cache.miss")
-            obs.inc("plan_cache.fallback")
-            obs.event("plan.resolve", op=op, key=_key_str(key),
-                      source="heuristic", variant=plan.variant)
+            key = infer_key(op, *args)
+            plan = default_planner.lookup(key)
+            if plan is None:
+                plan = heuristic_plan(op, key)
+                obs.inc("plan_cache.miss")
+                obs.inc("plan_cache.fallback")
+                obs.event("plan.resolve", op=op, key=_key_str(key),
+                          source="heuristic", variant=plan.variant)
+            else:
+                obs.inc("plan_cache.hit")
+                obs.event("plan.resolve", op=op, key=_key_str(key),
+                          source="cache", variant=plan.variant)
+            default_planner.put(key, plan)
         else:
-            obs.inc("plan_cache.hit")
-            obs.event("plan.resolve", op=op, key=_key_str(key),
-                      source="cache", variant=plan.variant)
-        default_planner.put(key, plan)
-    else:
-        obs.inc("plan_cache.pinned")
-    if variant is not None:
-        plan = plan.replace(variant=variant)
-    return plan
+            obs.inc("plan_cache.pinned")
+        if variant is not None:
+            plan = plan.replace(variant=variant)
+        return plan
 
 
 def run_op(op: str, plan: Plan, *args):
@@ -172,6 +196,7 @@ def run_op(op: str, plan: Plan, *args):
 # public ops
 # --------------------------------------------------------------------------
 
+@_public
 def sort(x, *, descending: bool = True, values=None, stable: bool = False,
          nan: Optional[str] = None, plan: Optional[Plan] = None,
          variant: Optional[str] = None):
@@ -190,8 +215,7 @@ def sort(x, *, descending: bool = True, values=None, stable: bool = False,
     NaN orders above everything (last ascending / first descending), both
     NaN signs one tie class, ``±0.0`` one tie class, ties in input order.
     """
-    _check_lane_width(x.shape[-1], "sort")
-    ik = _nan_keys("sort", x, nan)
+    ik = _validated("sort", x, nan, x.shape[-1])
     if ik is not None:
         perm = argsort(ik, descending=descending, plan=plan, variant=variant)
         keys = x[perm]
@@ -216,6 +240,7 @@ def sort(x, *, descending: bool = True, values=None, stable: bool = False,
     return out
 
 
+@_public
 def argsort(keys, *, descending: bool = True, nan: Optional[str] = None,
             plan: Optional[Plan] = None, variant: Optional[str] = None):
     """Stable argsort of 1-D keys, or row-wise over a 2-D batch.
@@ -228,8 +253,7 @@ def argsort(keys, *, descending: bool = True, nan: Optional[str] = None,
     transform of the float keys — bit-for-bit ``jnp.argsort(stable=True)``
     NaN semantics (guard.validate; see :func:`sort`).
     """
-    _check_lane_width(keys.shape[-1], "argsort")
-    ik = _nan_keys("argsort", keys, nan)
+    ik = _validated("argsort", keys, nan, keys.shape[-1])
     if ik is not None:
         keys = ik
     plan = _resolve("argsort", plan, variant, keys)
@@ -242,6 +266,7 @@ def argsort(keys, *, descending: bool = True, nan: Optional[str] = None,
     return perm
 
 
+@_public
 def merge(a, b, *, descending: bool = True, values=None,
           stable: bool = False, tie: Optional[str] = None,
           nan: Optional[str] = None,
@@ -266,7 +291,7 @@ def merge(a, b, *, descending: bool = True, values=None,
     ``jnp.sort``'s order, in the call's direction). Incompatible with
     ``tie='skew'``.
     """
-    ik_a = _nan_keys("merge", a, nan)
+    ik_a = _validated("merge", a, nan)
     if ik_a is not None:
         if tie == "skew":
             raise _validate.EngineInputError(
@@ -332,6 +357,7 @@ def _merge_kv(a, b, values, descending, plan, variant):
     return keys, vals
 
 
+@_public
 def topk(x, k: int, *, values=None, nan: Optional[str] = None,
          plan: Optional[Plan] = None, variant: Optional[str] = None):
     """(values, indices) of the k largest along the trailing axis,
@@ -345,8 +371,7 @@ def topk(x, k: int, *, values=None, nan: Optional[str] = None,
     above every real — NaN keys fill the leading slots when present,
     matching the sort-family policy; clean rows are untouched).
     """
-    _check_lane_width(x.shape[-1], "topk")
-    ik = _nan_keys("topk", x, nan)
+    ik = _validated("topk", x, nan, x.shape[-1])
     if ik is not None:
         pay = {"k": x} if values is None else {"k": x, "v": values}
         _, idx, pv = topk(ik, k, values=pay, plan=plan, variant=variant)
@@ -372,6 +397,7 @@ def _sample_sorted(op: str, key, logits, knob: float, temperature, plan,
     return out[0] if squeeze else out
 
 
+@_public
 def sample_topp(key, logits, p: float, *, temperature: float = 1.0,
                 plan: Optional[Plan] = None, variant: Optional[str] = None):
     """Nucleus (top-p) sampling: one token id per row of ``logits``.
@@ -388,6 +414,7 @@ def sample_topp(key, logits, p: float, *, temperature: float = 1.0,
                           variant)
 
 
+@_public
 def sample_minp(key, logits, min_p: float, *, temperature: float = 1.0,
                 plan: Optional[Plan] = None, variant: Optional[str] = None):
     """Min-p sampling: one token id per row of ``logits``.
@@ -400,6 +427,7 @@ def sample_minp(key, logits, min_p: float, *, temperature: float = 1.0,
                           plan, variant)
 
 
+@_public
 def segment_sort(keys, offsets, *, descending: bool = True, values=None,
                  stable: bool = False, cap: int = 0,
                  nan: Optional[str] = None,
@@ -422,8 +450,7 @@ def segment_sort(keys, offsets, *, descending: bool = True, values=None,
     ``nan="sort_last"`` sorts each segment by the monotone total-order
     transform (NaN last per segment ascending, ``jnp`` semantics).
     """
-    _check_lane_width(keys.shape[0], "segment_sort")
-    ik = _nan_keys("segment_sort", keys, nan)
+    ik = _validated("segment_sort", keys, nan, keys.shape[0])
     if ik is not None:
         pay = {"k": keys} if values is None else {"k": keys, "v": values}
         _, pv = segment_sort(ik, offsets, descending=descending, values=pay,
@@ -456,6 +483,7 @@ def segment_sort(keys, offsets, *, descending: bool = True, values=None,
     return out
 
 
+@_public
 def segment_argsort(keys, offsets, *, descending: bool = True, cap: int = 0,
                     nan: Optional[str] = None,
                     plan: Optional[Plan] = None,
@@ -472,8 +500,7 @@ def segment_argsort(keys, offsets, *, descending: bool = True, cap: int = 0,
     ``nan="sort_last"`` orders each segment by the monotone total-order
     transform — bit-for-bit per-segment ``jnp.argsort(stable=True)``.
     """
-    _check_lane_width(keys.shape[0], "segment_argsort")
-    ik = _nan_keys("segment_argsort", keys, nan)
+    ik = _validated("segment_argsort", keys, nan, keys.shape[0])
     if ik is not None:
         keys = ik
     segments.validate_offsets(offsets, keys.shape[0])
@@ -488,6 +515,7 @@ def segment_argsort(keys, offsets, *, descending: bool = True, cap: int = 0,
                   descending=descending, interpret=_interpret())
 
 
+@_public
 def merge_runs(keys, run_offsets, *, descending: bool = True, values=None,
                stable: bool = False, tie: Optional[str] = None, cap: int = 0,
                nan: Optional[str] = None,
@@ -515,8 +543,7 @@ def merge_runs(keys, run_offsets, *, descending: bool = True, values=None,
     same policy); incompatible with ``tie='skew'``.
     """
     del cap
-    _check_lane_width(keys.shape[0], "merge_runs")
-    ik = _nan_keys("merge_runs", keys, nan)
+    ik = _validated("merge_runs", keys, nan, keys.shape[0])
     if ik is not None:
         if tie == "skew":
             raise _validate.EngineInputError(
@@ -551,6 +578,7 @@ def merge_runs(keys, run_offsets, *, descending: bool = True, values=None,
     return mk, jax.tree.map(lambda v: v[mr], values)
 
 
+@_public
 def external_sort(keys, *, descending: bool = True, values=None,
                   stable: bool = False, tile_elems: int = 0, fan_in: int = 0,
                   nan: Optional[str] = None,
@@ -579,8 +607,7 @@ def external_sort(keys, *, descending: bool = True, values=None,
             "external_sort", f"expects a 1-D key array, got shape "
             f"{keys.shape}", shape=tuple(keys.shape))
     n = keys.shape[0]
-    _check_lane_width(n, "external_sort")
-    ik = _nan_keys("external_sort", keys, nan)
+    ik = _validated("external_sort", keys, nan, n)
     if ik is not None:
         pay = {"k": keys} if values is None else {"k": keys, "v": values}
         _, pv = external_sort(ik, descending=descending, values=pay,
@@ -613,6 +640,7 @@ def external_sort(keys, *, descending: bool = True, values=None,
     return mk, jax.tree.map(lambda v: v[mr], values)
 
 
+@_public
 def segment_merge(a, a_offsets, b, b_offsets, *, descending: bool = True,
                   plan: Optional[Plan] = None,
                   variant: Optional[str] = None):
@@ -652,12 +680,7 @@ class RouteResult(NamedTuple):
     keep: jax.Array      # (..., T*k) bool — False = over capacity (dropped)
 
 
-def _route_drops_cb(dropped) -> None:
-    """Host sink for the per-call dropped-pair count (``jax.debug.callback``
-    target — the keep mask only exists on device)."""
-    obs.inc("moe.dropped_tokens", int(dropped))
-
-
+@_public
 def moe_route(logits, k: int, capacity: int, *, values=None,
               plan: Optional[Plan] = None, variant: Optional[str] = None):
     """Route a chunk of tokens to expert capacity slabs in one planned op.
@@ -668,7 +691,9 @@ def moe_route(logits, k: int, capacity: int, *, values=None,
     semantics, bit-for-bit the historical ``segment_sort``-based dispatch).
     Returns a :class:`RouteResult` of (G, T*k) lanes in sorted pair order;
     scattering ``x[tokens]`` to ``slabs`` builds the (E, capacity, d) expert
-    slabs directly and ``weights * keep`` are the combine coefficients.
+    slabs directly and ``weights * keep`` are the combine coefficients;
+    ``~keep`` marks the pairs dropped past capacity (the caller counts
+    them: nothing is staged into the program to count them).
 
     The ``fused`` variant executes top-k, the stable expert sort and the
     capacity drop in ONE ``pallas_call`` per chunk, and the softmax
@@ -694,7 +719,8 @@ def moe_route(logits, k: int, capacity: int, *, values=None,
         raise ValueError(f"moe_route: k={k} outside [1, E={E}]")
     if capacity < 1:
         raise ValueError(f"moe_route: capacity={capacity} must be >= 1")
-    _check_lane_width(T * k, "moe_route")
+    with obs.span("engine.validate"):
+        _check_lane_width(T * k, "moe_route")
     logits = logits.astype(jnp.float32)
     plan = _resolve("moe_route", plan, variant, logits, k, capacity)
     plan = plan.replace(cap=int(capacity))
@@ -705,8 +731,6 @@ def moe_route(logits, k: int, capacity: int, *, values=None,
                  interpret=_interpret())
     e_s, t_s, perm, w_s, slab, keep = out
     keep = keep.astype(bool)
-    if obs.enabled():
-        jax.debug.callback(_route_drops_cb, keep.size - jnp.sum(keep))
     res = RouteResult(e_s, t_s, perm, w_s, slab, keep)
     if values is None:
         return res
@@ -715,6 +739,7 @@ def moe_route(logits, k: int, capacity: int, *, values=None,
     return res, pay
 
 
+@_public
 def moe_route_ep(logits, k: int, capacity: int, mesh, axis: str = "data", *,
                  plan: Optional[Plan] = None, variant: Optional[str] = None):
     """Expert-parallel routing across a mesh axis: tokens are sharded over
@@ -742,6 +767,7 @@ def moe_route_ep(logits, k: int, capacity: int, mesh, axis: str = "data", *,
 # sharded ops: sort / top-k across a device mesh (DESIGN.md §6)
 # --------------------------------------------------------------------------
 
+@_public
 def sharded_sort(x, mesh, axis: str = "data", *, payload=None,
                  plan: Optional[Plan] = None, variant: Optional[str] = None):
     """Sort a 1-D array sharded over ``axis`` of ``mesh``. Descending.
@@ -773,6 +799,7 @@ def sharded_sort(x, mesh, axis: str = "data", *, payload=None,
                   interpret=_interpret(), payload=payload)
 
 
+@_public
 def sharded_topk(x, k: int, mesh, axis: str = "data", *, payload=None,
                  plan: Optional[Plan] = None, variant: Optional[str] = None):
     """(values, global indices) of the k largest elements of a 1-D array

@@ -48,9 +48,8 @@ def call(op: str, name: str, *args, **kw):
     """Dispatch ``op`` to variant ``name`` under the engine's observability
     wrappers: a ``jax.named_scope`` labelling the variant in XLA profiler
     traces (always on — trace-time only), and, when ``repro.obs`` is
-    enabled, an ``engine.<op>.<variant>`` span timer. ``obs.configure
-    (block=True)`` makes the span wait for device work so eager timings
-    measure execution rather than async dispatch."""
+    enabled, an ``engine.<op>.<variant>`` span: for an eager call, the
+    jitted program's dispatch and enqueue."""
     from repro import obs
     fn = get(op, name)
     label = f"repro.engine.{op}.{name}"
@@ -58,10 +57,7 @@ def call(op: str, name: str, *args, **kw):
         with jax.named_scope(label):
             return fn(*args, **kw)
     with obs.span(f"engine.{op}.{name}"), jax.named_scope(label):
-        out = fn(*args, **kw)
-        if obs.blocking():
-            out = jax.block_until_ready(out)
-        return out
+        return fn(*args, **kw)
 
 
 def variants(op: str):

@@ -273,7 +273,12 @@ def _pallas_reduce(keys, offsets, ranks, m: int, sched: MergeSchedule,
                    descending: bool, interpret: bool):
     """Fused-pass tree: each pass collapses ``2^levels_per_pass`` runs per
     group in one ``pallas_call`` (the segmented pair kernel at one level,
-    the merge-tree kernel at two or more)."""
+    the merge-tree kernel at two or more).
+
+    Stage scopes: pass ``i`` (from 0) runs under
+    ``repro.schedule.pass<i>_L<levels>``, so a device trace counts the
+    passes a call made; the run bounds each pass reads (``starts``,
+    ``lens``) are computed under ``repro.schedule.run_bounds``."""
     from repro.kernels.merge_tree import merge_tree_runs, merge_tree_runs_kv
     from repro.kernels.segmented_merge import (segmented_merge_runs,
                                                segmented_merge_runs_kv)
@@ -281,7 +286,8 @@ def _pallas_reduce(keys, offsets, ranks, m: int, sched: MergeSchedule,
     m2 = _next_pow2(m)
     levels_total = m2.bit_length() - 1
     passes = 0
-    starts, lens = _pad_group_runs(offsets, m, m2)
+    with obs.kernel_scope("schedule.run_bounds"):
+        starts, lens = _pad_group_runs(offsets, m, m2)
     buf, rbuf = keys, ranks
     while m2 > 1:
         Lp = min(sched.levels_per_pass, m2.bit_length() - 1)
@@ -289,11 +295,10 @@ def _pallas_reduce(keys, offsets, ranks, m: int, sched: MergeSchedule,
         # (G, C) block buffer stays O(n) even with many runs per pass
         groups = max(starts.shape[0] >> Lp, 1)
         bo = max(sched.w, min(sched.block_out, _next_pow2(-(-n // groups))))
-        passes += 1
         obs.event("schedule.pass", executor="tree_pallas", levels=int(Lp),
                   runs=int(starts.shape[0]), n=int(n), block_out=int(bo),
                   kv=rbuf is not None)
-        with jax.named_scope(f"repro.schedule.pass_L{Lp}"):
+        with obs.kernel_scope(f"schedule.pass{passes}_L{Lp}"):
             if Lp == 1:
                 if rbuf is None:
                     buf = segmented_merge_runs(
@@ -316,10 +321,12 @@ def _pallas_reduce(keys, offsets, ranks, m: int, sched: MergeSchedule,
                         buf, rbuf, starts, lens, group=1 << Lp, n_out=n,
                         w=sched.w, block_out=bo, descending=descending,
                         interpret=interpret)
-        lens = lens.reshape(-1, 1 << Lp).sum(axis=1).astype(jnp.int32)
-        starts = jnp.concatenate(
-            [jnp.zeros((1,), jnp.int32),
-             jnp.cumsum(lens)[:-1]]).astype(jnp.int32)
+        passes += 1
+        with obs.kernel_scope("schedule.run_bounds"):
+            lens = lens.reshape(-1, 1 << Lp).sum(axis=1).astype(jnp.int32)
+            starts = jnp.concatenate(
+                [jnp.zeros((1,), jnp.int32),
+                 jnp.cumsum(lens)[:-1]]).astype(jnp.int32)
         m2 >>= Lp
     # the per-level tree would have taken `levels_total` HBM round trips;
     # the fused passes took `passes` — the difference is the saving this
@@ -571,7 +578,8 @@ def reduce_rows(rows, *, schedule: MergeSchedule, ranks=None,
     no repacking gather is needed on the vmapped path. Returns the flat
     merged groups (and ranks, when given)."""
     K, n = rows.shape
-    offsets = jnp.arange(K + 1, dtype=jnp.int32) * n
+    with obs.kernel_scope("schedule.run_bounds"):
+        offsets = jnp.arange(K + 1, dtype=jnp.int32) * n
     return merge_runs(rows.reshape(-1), offsets,
                       ranks=None if ranks is None else ranks.reshape(-1),
                       schedule=schedule, runs_per_group=runs_per_group,

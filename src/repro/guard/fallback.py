@@ -98,13 +98,16 @@ def guarded_call(op: str, plan, *args, **kw):
 
     Dispatches ``op`` with ``plan`` (passed down as ``plan=``); on a
     recoverable failure quarantines the rung and retries the next one. The
-    last rung's failure — or any non-recoverable error — propagates.
+    last rung's failure — or any non-recoverable error — propagates. The
+    ladder's own bookkeeping (the call's bucket and rungs) runs under the
+    ``engine.guard`` span, beside the dispatch's ``engine.<op>.<variant>``.
     """
     from repro.engine import registry
     from repro.engine.planner import _key_str, default_planner
 
-    key = _bucket(op, args)
-    rungs = _ladder(op, plan)
+    with obs.span("engine.guard"):
+        key = _bucket(op, args)
+        rungs = _ladder(op, plan)
     for i, variant in enumerate(rungs):
         last_rung = i + 1 == len(rungs)
         if not last_rung and key is not None \

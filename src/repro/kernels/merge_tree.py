@@ -320,10 +320,18 @@ def _tree_kernel(m_ref, *refs, w: int, L: int, C: int, Ha: int,
 # public entry points
 # --------------------------------------------------------------------------
 
+def _stage(name: str):
+    """Stage scope of a merge-tree pass: ``bank`` (the row-aligned bank),
+    ``grid`` (the flat (group, block) grid), ``corank`` (the nested co-rank
+    partition per grid step), ``kernel`` (the ``pallas_call``) and
+    ``unpad`` (the gather back to the flat layout)."""
+    return obs.kernel_scope(f"kernels.merge_tree.{name}")
+
+
 def _merge_tree_call(buf, ranks, starts, lens, *, group: int, n_out: int,
                      w: int, block_out: int, descending: bool,
                      interpret: bool):
-    from repro.kernels.segmented_merge import _build_bank
+    from repro.kernels.segmented_merge import _build_bank, _unpad_blocks
 
     kv = ranks is not None
     R = starts.shape[0]
@@ -336,68 +344,69 @@ def _merge_tree_call(buf, ranks, starts, lens, *, group: int, n_out: int,
         empty = jnp.zeros((n_out,), buf.dtype)
         return (empty, jnp.zeros((n_out,), jnp.int32)) if kv else empty
 
-    starts = starts.astype(jnp.int32)
-    lens = lens.astype(jnp.int32)
     C = max(w, min(block_out, _next_pow2(n_out)))
     C = (C // w) * w
     Ha = C // w + L + 2
     Hp = pad_rows(Ha)                     # rows each leaf window DMAs
+    ROWS = n_out // w + R * (Hp + 3)
 
     # --- row-aligned banks (one shared bank, one DMA window per leaf) ------
-    rows_per_run = -(-lens // w) + Hp + 2
-    row0 = jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                            jnp.cumsum(rows_per_run)]).astype(jnp.int32)
-    ROWS = n_out // w + R * (Hp + 3)
-    _, last_k = bound_keys(buf.dtype, descending)
-    kbank = _build_bank(buf, starts, lens, row0, ROWS, w, fill=last_k)
-    rbank = (_build_bank(ranks.astype(jnp.int32), starts, lens, row0, ROWS,
-                         w, fill=INVALID_RANK) if kv else None)
+    with _stage("bank"):
+        starts = starts.astype(jnp.int32)
+        lens = lens.astype(jnp.int32)
+        rows_per_run = -(-lens // w) + Hp + 2
+        row0 = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                jnp.cumsum(rows_per_run)]).astype(jnp.int32)
+        _, last_k = bound_keys(buf.dtype, descending)
+        kbank = _build_bank(buf, starts, lens, row0, ROWS, w, fill=last_k)
+        rbank = (_build_bank(ranks.astype(jnp.int32), starts, lens, row0,
+                             ROWS, w, fill=INVALID_RANK) if kv else None)
 
     # --- flat grid over (group, block) pairs -------------------------------
-    glen = lens.reshape(n_groups, group).sum(axis=1)
-    nb = -(-glen // C)
-    blk0 = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(nb)])
     G = n_out // C + n_groups
-    gsteps = jnp.arange(G, dtype=jnp.int32)
-    grp = jnp.clip(jnp.searchsorted(blk0, gsteps, side="right") - 1,
-                   0, n_groups - 1)
-    o = jnp.minimum((gsteps - blk0[grp]) * C, (glen[grp] // C) * C)
+    with _stage("grid"):
+        glen = lens.reshape(n_groups, group).sum(axis=1)
+        nb = -(-glen // C)
+        blk0 = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(nb)])
+        gsteps = jnp.arange(G, dtype=jnp.int32)
+        grp = jnp.clip(jnp.searchsorted(blk0, gsteps, side="right") - 1,
+                       0, n_groups - 1)
+        o = jnp.minimum((gsteps - blk0[grp]) * C, (glen[grp] // C) * C)
 
     # --- nested co-rank partition per grid step ----------------------------
     steps = max(1, math.ceil(math.log2(max(n_out, 2))) + 1)
-    meta = jax.vmap(lambda gr, oo: _tree_meta_one(
-        gr, oo, buf, ranks if kv else None, starts, lens, row0, group=group,
-        w=w, max_row=ROWS - Hp, steps=steps, descending=descending))(grp, o)
-    mspec, meta = meta_block(meta)                        # (G, 1, n_meta)
+    with _stage("corank"):
+        meta = jax.vmap(lambda gr, oo: _tree_meta_one(
+            gr, oo, buf, ranks if kv else None, starts, lens, row0,
+            group=group, w=w, max_row=ROWS - Hp, steps=steps,
+            descending=descending))(grp, o)
+        mspec, meta = meta_block(meta)                    # (G, 1, n_meta)
 
     banks = [kbank, rbank] if kv else [kbank]
     spec, _ = row_blocks(G, C, w, buf.dtype)
     kern = functools.partial(_tree_kernel, w=w, L=L, C=C, Ha=Ha, kv=kv,
                              descending=descending)
-    out = pl.pallas_call(
-        kern,
-        grid=(G,),
-        in_specs=[mspec] + [pl.BlockSpec(memory_space=pl.ANY)] * len(banks),
-        out_specs=[spec] * len(banks),
-        scratch_shapes=[pltpu.VMEM((group, Hp, w), b.dtype) for b in banks]
-        + tree_scratch(w=w, L=L, C=C, key_dtype=buf.dtype, kv=kv)
-        + [pltpu.SemaphoreType.DMA((group * len(banks),))],
-        out_shape=[row_blocks(G, C, w, b.dtype)[1] for b in banks],
-        interpret=interpret,
-        name="flims_merge_tree",
-    )(meta, *banks)
-    out = [o.reshape(G, C) for o in out]
+    with _stage("kernel"):
+        out = pl.pallas_call(
+            kern,
+            grid=(G,),
+            in_specs=[mspec]
+            + [pl.BlockSpec(memory_space=pl.ANY)] * len(banks),
+            out_specs=[spec] * len(banks),
+            scratch_shapes=[pltpu.VMEM((group, Hp, w), b.dtype)
+                            for b in banks]
+            + tree_scratch(w=w, L=L, C=C, key_dtype=buf.dtype, kv=kv)
+            + [pltpu.SemaphoreType.DMA((group * len(banks),))],
+            out_shape=[row_blocks(G, C, w, b.dtype)[1] for b in banks],
+            interpret=interpret,
+            name="flims_merge_tree",
+        )(meta, *banks)
 
     # --- gather padded blocks back to the flat group-order layout ----------
-    goff = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(glen)])
-    i = jnp.arange(n_out, dtype=jnp.int32)
-    s = jnp.clip(jnp.searchsorted(goff, i, side="right") - 1,
-                 0, n_groups - 1)
-    pos = i - goff[s]
-    gg = jnp.clip(blk0[s] + pos // C, 0, G - 1)
-    if kv:
-        return out[0][gg, pos % C], out[1][gg, pos % C]
-    return out[0][gg, pos % C]
+    with _stage("unpad"):
+        out = _unpad_blocks([o.reshape(G, C) for o in out], glen, blk0,
+                            n_out, G, C)
+        return tuple(out) if kv else out[0]
 
 
 @functools.partial(jax.jit, static_argnames=("group", "n_out", "w",

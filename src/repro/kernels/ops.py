@@ -67,14 +67,18 @@ def kernel_sort(x: jnp.ndarray, *, chunk: int = 512, w: int = 128,
     while m2 < m:
         m2 *= 2
     n_pad = m2 * c
-    xp = jnp.pad(x, (0, n_pad - n), constant_values=sentinel_for(x.dtype))
-    rows = sort_chunks_pallas(xp.reshape(-1, c), interpret=interpret)
+    with obs.kernel_scope("kernels.kernel_sort.pad"):
+        xp = jnp.pad(x, (0, n_pad - n),
+                     constant_values=sentinel_for(x.dtype))
+    with obs.kernel_scope("kernels.kernel_sort.chunk_sort"):
+        rows = sort_chunks_pallas(xp.reshape(-1, c), interpret=interpret)
     ww = min(w, c)
     sched = MergeSchedule("tree_pallas", levels_per_pass=levels, w=ww,
                           block_out=max(ww, 4096))
     merged = reduce_rows(rows, schedule=sched, interpret=interpret)
-    out = merged[:n]
-    return out if descending else out[::-1]
+    with obs.kernel_scope("kernels.kernel_sort.trim"):
+        out = merged[:n]
+        return out if descending else out[::-1]
 
 
 @functools.partial(jax.jit, static_argnames=("chunk", "w", "descending",
@@ -106,14 +110,18 @@ def kernel_argsort(keys: jnp.ndarray, *, chunk: int = 256, w: int = 32,
         m2 *= 2
     n_pad = m2 * c
     _, last = bound_keys(keys.dtype, descending)
-    kp = jnp.pad(keys, (0, n_pad - n), constant_values=last)
-    rp = jnp.where(jnp.arange(n_pad) < n,
-                   jnp.arange(n_pad, dtype=jnp.int32), INVALID_RANK)
-    k2, r2 = sort_chunks_kv_pallas(kp.reshape(-1, c), rp.reshape(-1, c),
-                                   descending=descending, interpret=interpret)
+    with obs.kernel_scope("kernels.kernel_argsort.pad"):
+        kp = jnp.pad(keys, (0, n_pad - n), constant_values=last)
+        rp = jnp.where(jnp.arange(n_pad) < n,
+                       jnp.arange(n_pad, dtype=jnp.int32), INVALID_RANK)
+    with obs.kernel_scope("kernels.kernel_argsort.chunk_sort"):
+        k2, r2 = sort_chunks_kv_pallas(kp.reshape(-1, c), rp.reshape(-1, c),
+                                       descending=descending,
+                                       interpret=interpret)
     ww = min(w, c)
     sched = MergeSchedule("tree_pallas", levels_per_pass=levels, w=ww,
                           block_out=max(ww, 4096))
     _, perm = reduce_rows(k2, ranks=r2, schedule=sched,
                           descending=descending, interpret=interpret)
-    return perm[:n]
+    with obs.kernel_scope("kernels.kernel_argsort.trim"):
+        return perm[:n]

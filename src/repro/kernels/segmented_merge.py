@@ -133,6 +133,26 @@ def _corank_runs(o, la, lb, astart, bstart, a, b, steps: int):
     return lo
 
 
+def _stage(name: str):
+    """Stage scope of the segmented run merges: ``bank`` (the row-aligned
+    banks), ``grid`` (the flat (segment, block) grid), ``corank`` (the
+    per-step co-rank search), ``kernel`` (the ``pallas_call``) and
+    ``unpad`` (the gather back to the flat layout)."""
+    return obs.kernel_scope(f"kernels.segmented_merge.{name}")
+
+
+def _unpad_blocks(outs, lo_len, blk0, n_out: int, G: int, C: int):
+    """Gather each padded (G, C) block output back to the flat ragged
+    layout of the R merged runs (lengths ``lo_len``)."""
+    R = lo_len.shape[0]
+    oo = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(lo_len)])
+    i = jnp.arange(n_out, dtype=jnp.int32)
+    s = jnp.clip(jnp.searchsorted(oo, i, side="right") - 1, 0, R - 1)
+    pos = i - oo[s]
+    gg = jnp.clip(blk0[s] + pos // C, 0, G - 1)
+    return [out[gg, pos % C] for out in outs]
+
+
 @functools.partial(jax.jit,
                    static_argnames=("n_out", "w", "block_out", "interpret"))
 @obs.scoped("kernels.segmented_merge_runs")
@@ -156,56 +176,56 @@ def segmented_merge_runs(a, b, a_starts, a_lens, b_starts, b_lens, *,
     Ha = cycles + 2
     Hp = pad_rows(Ha)                     # rows each step's window DMAs
     G = n_out // C + R                    # >= sum ceil(out_len_s / C)
-
-    a_starts = a_starts.astype(jnp.int32)
-    b_starts = b_starts.astype(jnp.int32)
-    la = a_lens.astype(jnp.int32)
-    lb = b_lens.astype(jnp.int32)
-    lo_len = la + lb
-
-    # --- flat grid over (segment, block) pairs -----------------------------
-    nb = -(-lo_len // C)                              # blocks per segment
-    blk0 = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(nb)])
-    g = jnp.arange(G, dtype=jnp.int32)
-    seg = jnp.clip(jnp.searchsorted(blk0, g, side="right") - 1, 0, R - 1)
-    # tail steps past the last real block recompute segment-final co-ranks;
-    # their outputs are never gathered.
-    o = jnp.minimum((g - blk0[seg]) * C, (lo_len[seg] // C) * C)
-
-    # --- per-run row-aligned banks -----------------------------------------
-    ra = -(-la // w) + Hp + 2
-    rb = -(-lb // w) + Hp + 2
-    ra0 = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(ra)])
-    rb0 = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(rb)])
     RA = n_out // w + R * (Hp + 3)                    # static row capacity
     RB = RA
-    abank = _build_bank(a, a_starts, la, ra0, RA, w)
-    bbank = _build_bank(b, b_starts, lb, rb0, RB, w)
+
+    # --- flat grid over (segment, block) pairs -----------------------------
+    with _stage("grid"):
+        a_starts = a_starts.astype(jnp.int32)
+        b_starts = b_starts.astype(jnp.int32)
+        la = a_lens.astype(jnp.int32)
+        lb = b_lens.astype(jnp.int32)
+        lo_len = la + lb
+        nb = -(-lo_len // C)                          # blocks per segment
+        blk0 = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(nb)])
+        g = jnp.arange(G, dtype=jnp.int32)
+        seg = jnp.clip(jnp.searchsorted(blk0, g, side="right") - 1, 0, R - 1)
+        # tail steps past the last real block recompute segment-final
+        # co-ranks; their outputs are never gathered.
+        o = jnp.minimum((g - blk0[seg]) * C, (lo_len[seg] // C) * C)
+
+    # --- per-run row-aligned banks -----------------------------------------
+    with _stage("bank"):
+        ra = -(-la // w) + Hp + 2
+        rb = -(-lb // w) + Hp + 2
+        ra0 = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(ra)])
+        rb0 = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(rb)])
+        abank = _build_bank(a, a_starts, la, ra0, RA, w)
+        bbank = _build_bank(b, b_starts, lb, rb0, RB, w)
 
     # --- per-(segment, block) co-ranks (vectorised binary search) ----------
     steps = max(1, math.ceil(math.log2(max(n_out, 2))) + 1)
-    acut = jax.vmap(lambda oo, s: _corank_runs(
-        oo, la[s], lb[s], a_starts[s], b_starts[s], a, b, steps))(o, seg)
-    acut = acut.astype(jnp.int32)
-    bcut = o - acut
-    arow0 = jnp.minimum(ra0[seg] + acut // w, RA - Hp)
-    brow0 = jnp.minimum(rb0[seg] + bcut // w, RB - Hp)
-    la0 = acut % w
-    lb0 = bcut % w
+    with _stage("corank"):
+        acut = jax.vmap(lambda oo, s: _corank_runs(
+            oo, la[s], lb[s], a_starts[s], b_starts[s], a, b, steps))(o, seg)
+        acut = acut.astype(jnp.int32)
+        bcut = o - acut
+        arow0 = jnp.minimum(ra0[seg] + acut // w, RA - Hp)
+        brow0 = jnp.minimum(rb0[seg] + bcut // w, RB - Hp)
+        la0 = acut % w
+        lb0 = bcut % w
 
-    (out,) = merge_call(_merge_kernel, [abank, bbank],
-                        jnp.stack([arow0, brow0, la0, lb0], axis=1),
-                        G=G, C=C, w=w, Ha=Ha,
-                        out_dtypes=[a.dtype], name="flims_segmented_merge",
-                        interpret=interpret)
+    with _stage("kernel"):
+        (out,) = merge_call(_merge_kernel, [abank, bbank],
+                            jnp.stack([arow0, brow0, la0, lb0], axis=1),
+                            G=G, C=C, w=w, Ha=Ha,
+                            out_dtypes=[a.dtype],
+                            name="flims_segmented_merge",
+                            interpret=interpret)
 
     # --- gather padded blocks back to the flat ragged layout ---------------
-    oo = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(lo_len)])
-    i = jnp.arange(n_out, dtype=jnp.int32)
-    s = jnp.clip(jnp.searchsorted(oo, i, side="right") - 1, 0, R - 1)
-    pos = i - oo[s]
-    gg = jnp.clip(blk0[s] + pos // C, 0, G - 1)
-    return out[gg, pos % C]
+    with _stage("unpad"):
+        return _unpad_blocks([out], lo_len, blk0, n_out, G, C)[0]
 
 
 @functools.partial(jax.jit, static_argnames=("w", "block_out", "interpret"))
@@ -305,55 +325,55 @@ def segmented_merge_runs_kv(a, ra, b, rb, a_starts, a_lens, b_starts, b_lens,
     Ha = cycles + 2
     Hp = pad_rows(Ha)
     G = n_out // C + R
-
-    a_starts = a_starts.astype(jnp.int32)
-    b_starts = b_starts.astype(jnp.int32)
-    la = a_lens.astype(jnp.int32)
-    lb = b_lens.astype(jnp.int32)
-    lo_len = la + lb
-
-    nb = -(-lo_len // C)
-    blk0 = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(nb)])
-    g = jnp.arange(G, dtype=jnp.int32)
-    seg = jnp.clip(jnp.searchsorted(blk0, g, side="right") - 1, 0, R - 1)
-    o = jnp.minimum((g - blk0[seg]) * C, (lo_len[seg] // C) * C)
-
-    rra = -(-la // w) + Hp + 2
-    rrb = -(-lb // w) + Hp + 2
-    ra0 = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(rra)])
-    rb0 = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(rrb)])
     RA = n_out // w + R * (Hp + 3)
     RB = RA
-    _, lastK = bound_keys(a.dtype, descending)
-    abank = _build_bank(a, a_starts, la, ra0, RA, w, fill=lastK)
-    bbank = _build_bank(b, b_starts, lb, rb0, RB, w, fill=lastK)
-    arbank = _build_bank(ra, a_starts, la, ra0, RA, w, fill=INVALID_RANK)
-    brbank = _build_bank(rb, b_starts, lb, rb0, RB, w, fill=INVALID_RANK)
+
+    with _stage("grid"):
+        a_starts = a_starts.astype(jnp.int32)
+        b_starts = b_starts.astype(jnp.int32)
+        la = a_lens.astype(jnp.int32)
+        lb = b_lens.astype(jnp.int32)
+        lo_len = la + lb
+        nb = -(-lo_len // C)
+        blk0 = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(nb)])
+        g = jnp.arange(G, dtype=jnp.int32)
+        seg = jnp.clip(jnp.searchsorted(blk0, g, side="right") - 1, 0, R - 1)
+        o = jnp.minimum((g - blk0[seg]) * C, (lo_len[seg] // C) * C)
+
+    with _stage("bank"):
+        rra = -(-la // w) + Hp + 2
+        rrb = -(-lb // w) + Hp + 2
+        ra0 = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(rra)])
+        rb0 = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(rrb)])
+        _, lastK = bound_keys(a.dtype, descending)
+        abank = _build_bank(a, a_starts, la, ra0, RA, w, fill=lastK)
+        bbank = _build_bank(b, b_starts, lb, rb0, RB, w, fill=lastK)
+        arbank = _build_bank(ra, a_starts, la, ra0, RA, w, fill=INVALID_RANK)
+        brbank = _build_bank(rb, b_starts, lb, rb0, RB, w, fill=INVALID_RANK)
 
     steps = max(1, math.ceil(math.log2(max(n_out, 2))) + 1)
-    acut = jax.vmap(lambda oo, s: _corank_runs_kv(
-        oo, la[s], lb[s], a_starts[s], b_starts[s], a, ra, b, rb, steps,
-        descending))(o, seg)
-    acut = acut.astype(jnp.int32)
-    bcut = o - acut
-    arow0 = jnp.minimum(ra0[seg] + acut // w, RA - Hp)
-    brow0 = jnp.minimum(rb0[seg] + bcut // w, RB - Hp)
-    la0 = acut % w
-    lb0 = bcut % w
+    with _stage("corank"):
+        acut = jax.vmap(lambda oo, s: _corank_runs_kv(
+            oo, la[s], lb[s], a_starts[s], b_starts[s], a, ra, b, rb, steps,
+            descending))(o, seg)
+        acut = acut.astype(jnp.int32)
+        bcut = o - acut
+        arow0 = jnp.minimum(ra0[seg] + acut // w, RA - Hp)
+        brow0 = jnp.minimum(rb0[seg] + bcut // w, RB - Hp)
+        la0 = acut % w
+        lb0 = bcut % w
 
-    ok, orr = merge_call(_merge_kv_kernel, [abank, arbank, bbank, brbank],
-                         jnp.stack([arow0, brow0, la0, lb0], axis=1),
-                         G=G, C=C, w=w, Ha=Ha,
-                         out_dtypes=[a.dtype, jnp.int32],
-                         name="flims_segmented_merge_kv",
-                         interpret=interpret, descending=descending)
+    with _stage("kernel"):
+        ok, orr = merge_call(_merge_kv_kernel,
+                             [abank, arbank, bbank, brbank],
+                             jnp.stack([arow0, brow0, la0, lb0], axis=1),
+                             G=G, C=C, w=w, Ha=Ha,
+                             out_dtypes=[a.dtype, jnp.int32],
+                             name="flims_segmented_merge_kv",
+                             interpret=interpret, descending=descending)
 
-    oo = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(lo_len)])
-    i = jnp.arange(n_out, dtype=jnp.int32)
-    s = jnp.clip(jnp.searchsorted(oo, i, side="right") - 1, 0, R - 1)
-    pos = i - oo[s]
-    gg = jnp.clip(blk0[s] + pos // C, 0, G - 1)
-    return ok[gg, pos % C], orr[gg, pos % C]
+    with _stage("unpad"):
+        return tuple(_unpad_blocks([ok, orr], lo_len, blk0, n_out, G, C))
 
 
 # --------------------------------------------------------------------------

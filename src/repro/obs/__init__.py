@@ -37,13 +37,13 @@ What gets recorded (the event taxonomy — DESIGN.md §7.1):
   ``jax.debug.callback`` — one event per participating device)
 - ``moe.route``           one per ``engine.moe_route`` call: group/token/
   expert geometry, k, capacity, and the serving variant — its count is the
-  one-pallas_call-per-chunk claim (DESIGN.md §9); the companion
-  ``moe.dropped_tokens`` counter tallies pairs past capacity per execution
-  (debug callback)
+  one-pallas_call-per-chunk claim (DESIGN.md §9); the caller counts dropped
+  pairs from ``RouteResult.keep``
 - ``moe.route_ep.plan``   the expert-parallel geometry: device count, local
   tokens, candidate cap, and the local route variant
 - ``moe.route_ep.exec``   owner-side merge outcome per device per run:
-  arrived candidates and globally-dropped pairs (debug callback)
+  arrived candidates and globally-dropped pairs (debug callback; the
+  companion ``moe.dropped_tokens`` counter tallies the drops)
 - ``serve.admit`` / ``serve.retire``  one per scheduler admission /
   retirement: request uid, slot, prompt/output length, finish reason
   (DESIGN.md §10); the companion counters ``serve.submitted`` /
@@ -51,7 +51,7 @@ What gets recorded (the event taxonomy — DESIGN.md §7.1):
   flow and emitted tokens, and ``serve.trace`` counts decode/prefill
   compilations (trace-time increment — the no-retrace acceptance contract
   reads it). Gauges ``serve.live_slots`` / ``serve.waiting`` /
-  ``serve.kv_free`` / ``serve.traces`` track occupancy; span timers
+  ``serve.kv_free`` / ``serve.traces`` track occupancy; spans
   ``serve.step`` / ``serve.prefill`` feed the p50/p99 the serving stats
   line reports.
 - ``serve.reject``          one per refused submission (structured
@@ -71,11 +71,24 @@ What gets recorded (the event taxonomy — DESIGN.md §7.1):
   so it fires per executed call; counters ``guard.verify.checked`` /
   ``guard.verify.fail`` feed the CI chaos job's zero-failure assertion
 
-Span timers (``obs.span``) record host wall time into bounded histograms
-and, when a profiler is attached, open a ``jax.profiler.TraceAnnotation``
-so the region is visible in the trace viewer; ``jax.named_scope`` labels on
-every registry dispatch and kernel entry make the pallas_call variants
-identifiable in XLA profiles regardless of the enabled flag.
+Spans (``obs.span``) mark the layer boundaries of an eager engine call:
+``engine.<op>`` (the public call) and, nested in it, ``engine.validate``
+(lane width, NaN policy), ``engine.plan`` (plan resolution),
+``engine.guard`` (the fallback ladder's bookkeeping) and
+``engine.<op>.<variant>`` (the registry dispatch: the jitted program's
+dispatch and enqueue). Each span is recorded as ``{name, id, parent, call,
+start_ns, end_ns}`` into a bounded ring (``snapshot()["spans"]``) and into
+the ``name`` timer histogram; every span of one public call shares its
+``call`` id. While enabled a span also opens a
+``jax.profiler.TraceAnnotation`` carrying the same ``call`` id, so under a
+profiler it lands on the trace's host plane, on the clock the device trace
+is aligned to. ``jax.named_scope`` labels (``repro.*``, DESIGN.md §7.2) name
+the stages of the compiled programs whether or not obs is enabled; they
+reach each device op's event metadata (``tf_op``) in a profiler trace.
+
+Turning obs on compiles no different program: no instrumentation site
+stages anything into traced code on the flag, except the sharded entry
+points' documented static ``record`` argument (their debug callbacks).
 
 Trace-time semantics: events fired from inside traced code (plan lookup
 under ``jit``, schedule passes) are emitted when the decision is MADE —
@@ -86,53 +99,38 @@ a debug callback, so it fires per run (and per device under ``shard_map``).
 from __future__ import annotations
 
 import contextlib
+import itertools
+import threading
 import time
 from typing import Callable, Optional
 
-from repro.obs.metrics import Registry, percentile, plain
+from repro.obs.metrics import Registry, percentile, plain, self_times
 
 __all__ = [
-    "enable", "disable", "enabled", "blocking", "configure",
+    "enable", "disable", "enabled",
     "inc", "gauge", "observe", "event", "on", "span", "kernel_scope",
     "scoped", "snapshot", "report", "reset", "registry", "percentile",
-    "plain",
+    "plain", "self_times",
 ]
 
 #: the process-wide registry every instrumentation site writes to
 registry = Registry()
 
 _enabled = False
-_block = False
 
 
-def configure(*, block: Optional[bool] = None) -> None:
-    """Tune recording behaviour. ``block=True`` makes ``span`` wait for the
-    spanned op's device work (``jax.block_until_ready``) so eager span
-    timings measure execution, not async dispatch; leave False for
-    dispatch-latency semantics and zero interference."""
-    global _block
-    if block is not None:
-        _block = bool(block)
-
-
-def enable(*, block: Optional[bool] = None) -> None:
+def enable() -> None:
     global _enabled
     _enabled = True
-    configure(block=block)
 
 
 def disable() -> None:
-    global _enabled, _block
+    global _enabled
     _enabled = False
-    _block = False
 
 
 def enabled() -> bool:
     return _enabled
-
-
-def blocking() -> bool:
-    return _enabled and _block
 
 
 # --------------------------------------------------------------------------
@@ -166,32 +164,56 @@ def on(kind: str, fn: Callable) -> Callable:
     return registry.on(kind, fn)
 
 
-@contextlib.contextmanager
-def span(name: str):
-    """Host wall-time span: records into the ``name`` timer histogram and
-    annotates the region for the profiler. No-op (and no timestamps taken)
-    while disabled."""
-    if not _enabled:
-        yield
-        return
-    ctx = contextlib.nullcontext()
-    try:
+#: span ids and call ids: one counter each, shared by every thread
+_span_ids = itertools.count(1)
+_call_ids = itertools.count(1)
+#: the spans open on each thread, innermost last: (span id, call id)
+_open = threading.local()
+_OFF = contextlib.nullcontext()
+
+
+class _Span:
+    __slots__ = ("name", "id", "parent", "call", "start", "note")
+
+    def __init__(self, name: str):
+        self.name = name
+
+    def __enter__(self):
+        stack = _open.__dict__.setdefault("stack", [])
+        self.parent, self.call = stack[-1] if stack else (
+            None, next(_call_ids))
+        self.id = next(_span_ids)
+        stack.append((self.id, self.call))
         from jax.profiler import TraceAnnotation
-        ctx = TraceAnnotation(name)
-    except Exception:
-        pass
-    t0 = time.perf_counter()
-    try:
-        with ctx:
-            yield
-    finally:
-        registry.observe(name, time.perf_counter() - t0)
+        self.note = TraceAnnotation(self.name, call=self.call)
+        self.note.__enter__()
+        self.start = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc):
+        end = time.perf_counter_ns()
+        self.note.__exit__(*exc)
+        _open.stack.pop()
+        registry.span(self.name, self.id, self.parent, self.call,
+                      self.start, end)
+        return False
+
+
+def span(name: str):
+    """Context manager marking one layer boundary: records ``{name, id,
+    parent, call, start_ns, end_ns}`` into the span ring and the ``name``
+    timer, and opens a ``jax.profiler.TraceAnnotation(name, call=...)``.
+    The outermost open span of the thread starts a new call id; nested
+    spans take their parent's. While disabled it is a shared no-op context
+    (one flag check, no timestamps)."""
+    return _Span(name) if _enabled else _OFF
 
 
 def kernel_scope(name: str):
-    """``jax.named_scope`` labelling a kernel entry point so its ops (and
-    pallas_calls) are identifiable in XLA profiler traces. Always on — the
-    label only exists at trace time and costs nothing at run time."""
+    """``jax.named_scope`` labelling a stage of a compiled program as
+    ``repro.<name>``: its ops carry the label in their HLO metadata, which
+    a profiler trace keeps per device op (``tf_op``). Always on — the label
+    only exists at trace time and costs nothing at run time."""
     import jax
     return jax.named_scope(f"repro.{name}")
 
@@ -216,8 +238,9 @@ def scoped(name: str):
 
 def snapshot(kinds: Optional[tuple] = None) -> dict:
     """One JSON-clean dict of everything recorded so far: counters, gauges,
-    timer summaries (count/total/p50/p99/max in µs), and the event ring
-    (optionally filtered to ``kinds``). Round-trips through ``json``."""
+    timer summaries (count/total/p50/p99/max in µs), the event ring
+    (optionally filtered to ``kinds``) and the span ring (``spans``, oldest
+    first). Round-trips through ``json``."""
     snap = registry.snapshot(kinds)
     snap["enabled"] = _enabled
     return snap
@@ -230,6 +253,6 @@ def report(snap: Optional[dict] = None) -> str:
 
 
 def reset() -> None:
-    """Clear every counter, gauge, timer, and event (the enabled flag and
-    subscriptions survive)."""
+    """Clear every counter, gauge, timer, event and span (the enabled flag
+    and subscriptions survive)."""
     registry.reset()

@@ -9,7 +9,10 @@ The storage layer of ``repro.obs`` (DESIGN.md §7). One ``Registry`` holds
 - **events**     a bounded ring of structured records ``{"kind", "data"}``
   for the engine decisions that would otherwise vanish (plan resolution,
   autotune candidates, the sharded sort's selected cap rung, schedule
-  passes), plus subscriber hooks per kind.
+  passes), plus subscriber hooks per kind;
+- **spans**      a bounded ring of ``{"name", "id", "parent", "call",
+  "start_ns", "end_ns"}`` records, one per closed ``obs.span`` (a span
+  also feeds the timer of its name).
 
 Everything is guarded by one lock — instrumentation sites go through the
 module-level fast path in ``repro.obs`` which checks the enabled flag first,
@@ -27,6 +30,7 @@ from typing import Callable, Dict, List, Optional
 #: to never matter for memory
 MAX_EVENTS = 4096
 MAX_SAMPLES = 512
+MAX_SPANS = 4096
 
 
 def plain(v):
@@ -59,6 +63,17 @@ def percentile(samples, q: float) -> float:
     s = sorted(samples)
     idx = min(len(s) - 1, max(0, int(round(q / 100.0 * (len(s) - 1)))))
     return s[idx]
+
+
+def self_times(spans) -> Dict[int, int]:
+    """Own time of each span record, by span id: its duration less the
+    durations of its direct children (children nest inside their parent,
+    on its thread)."""
+    out = {s["id"]: s["end_ns"] - s["start_ns"] for s in spans}
+    for s in spans:
+        if s["parent"] in out:
+            out[s["parent"]] -= s["end_ns"] - s["start_ns"]
+    return out
 
 
 class Timer:
@@ -101,6 +116,7 @@ class Registry:
         self.gauges: Dict[str, float] = {}
         self.timers: Dict[str, Timer] = {}
         self.events: deque = deque(maxlen=max_events)
+        self.spans: deque = deque(maxlen=MAX_SPANS)
         self._hooks: Dict[str, List[Callable]] = {}
 
     # -- write paths (only reached when obs is enabled) --------------------
@@ -118,6 +134,17 @@ class Registry:
             if t is None:
                 t = self.timers[name] = Timer()
             t.observe(seconds)
+
+    def span(self, name: str, span_id: int, parent: Optional[int],
+             call: int, start_ns: int, end_ns: int) -> None:
+        rec = {"name": name, "id": span_id, "parent": parent, "call": call,
+               "start_ns": start_ns, "end_ns": end_ns}
+        with self._lock:
+            self.spans.append(rec)
+            t = self.timers.get(name)
+            if t is None:
+                t = self.timers[name] = Timer()
+            t.observe((end_ns - start_ns) * 1e-9)
 
     def event(self, kind: str, **data) -> None:
         rec = {"kind": kind, "data": {k: plain(v) for k, v in data.items()}}
@@ -148,6 +175,7 @@ class Registry:
                 "gauges": dict(self.gauges),
                 "timers": {k: t.summary() for k, t in self.timers.items()},
                 "events": events,
+                "spans": [dict(r) for r in self.spans],
             }
 
     def reset(self) -> None:
@@ -156,3 +184,4 @@ class Registry:
             self.gauges.clear()
             self.timers.clear()
             self.events.clear()
+            self.spans.clear()

@@ -1,5 +1,5 @@
 """Rendering for ``obs.snapshot()``: the human-readable ``obs.report()``
-text and a compact one-line stats summary for serving loops."""
+text and the one-line serving stats summary."""
 from __future__ import annotations
 
 from typing import Optional
@@ -48,28 +48,32 @@ def render_report(snap: dict) -> str:
         if len(events) > 12:
             lines.insert(len(lines) - 12, f"    ... showing last 12 of "
                                           f"{len(events)}")
+    spans = snap.get("spans", [])
+    if spans:
+        lines.extend(_render_spans(spans))
     if len(lines) == 1:
         lines.append("  (empty)")
     return "\n".join(lines)
 
 
-def stats_line(step: int, window_s, batch: int,
-               counters: Optional[dict] = None) -> str:
-    """One periodic serving-stats line: latency percentiles over the recent
-    window of per-step wall times, throughput, and plan-cache counters."""
-    from repro.obs.metrics import percentile
-    ws = list(window_s)
-    p50 = percentile(ws, 50) * 1e3
-    p99 = percentile(ws, 99) * 1e3
-    tput = batch * len(ws) / sum(ws) if ws and sum(ws) > 0 else 0.0
-    c = counters or {}
-    line = (f"[stats] step={step} p50={p50:.2f}ms p99={p99:.2f}ms "
-            f"tok_s={tput:.1f} cache_hit={c.get('plan_cache.hit', 0)} "
-            f"cache_miss={c.get('plan_cache.miss', 0)} "
-            f"fallback={c.get('plan_cache.fallback', 0)}")
-    if "moe.dropped_tokens" in c:        # only when MoE routing ran observed
-        line += f" moe_drops={c['moe.dropped_tokens']}"
-    return line
+def _render_spans(spans, calls: int = 4) -> list:
+    """The span trees of the last ``calls`` call ids: each span's duration
+    and own time (duration less its children), children indented."""
+    from repro.obs.metrics import self_times
+    own = self_times(spans)
+    ids = sorted({s["call"] for s in spans})
+    keep = set(ids[-calls:])
+    depth = {}
+    out = [f"  spans: {len(spans)} in {len(ids)} calls; last "
+           f"{len(keep)} calls (duration / own time):"]
+    for s in sorted((s for s in spans if s["call"] in keep),
+                    key=lambda s: (s["call"], s["start_ns"], s["id"])):
+        depth[s["id"]] = depth.get(s["parent"], -1) + 1
+        name = "  " * depth[s["id"]] + s["name"]
+        dur = (s["end_ns"] - s["start_ns"]) / 1e3
+        out.append(f"    [call {s['call']}] {name:<40} {_fmt_us(dur):<8} "
+                   f"{_fmt_us(own[s['id']] / 1e3)}")
+    return out
 
 
 def serve_stats_line(snap: dict, step: Optional[int] = None) -> str:

@@ -8,6 +8,7 @@ rung, and per-variant span timings — plus the zero-overhead-when-disabled
 contract.
 """
 import json
+import re
 
 import numpy as np
 import pytest
@@ -49,6 +50,7 @@ class TestDisabledIsNoop:
         assert snap["gauges"] == {}
         assert snap["timers"] == {}
         assert snap["events"] == []
+        assert snap["spans"] == []
         assert snap["enabled"] is False
 
     def test_engine_ops_record_nothing_while_disabled(self):
@@ -280,16 +282,98 @@ class TestSnapshotAndReport:
         assert snap["counters"] == {} and snap["enabled"] is True
 
 
-class TestStatsLine:
-    def test_stats_line_format(self):
-        from repro.obs.reporting import stats_line
-        line = stats_line(32, [0.01, 0.02, 0.03], batch=4,
-                          counters={"plan_cache.hit": 5,
-                                    "plan_cache.miss": 2})
-        assert line.startswith("[stats] step=32 ")
-        assert "p50=20.00ms" in line
-        assert "cache_hit=5" in line and "cache_miss=2" in line
+class TestSpans:
+    def test_span_records_parent_call_and_self_time(self):
+        obs.enable()
+        with obs.span("outer"):
+            with obs.span("inner"):
+                with obs.span("leaf"):
+                    pass
+            with obs.span("inner"):
+                pass
+        with obs.span("next"):
+            pass
+        spans = {s["id"]: s for s in obs.snapshot()["spans"]}
+        by = lambda name: [s for s in spans.values() if s["name"] == name]
+        outer, nxt = by("outer")[0], by("next")[0]
+        assert outer["parent"] is None and nxt["parent"] is None
+        assert nxt["call"] != outer["call"]
+        for s in by("inner") + by("leaf"):
+            assert s["call"] == outer["call"]
+            parent = spans[s["parent"]]
+            assert parent["start_ns"] <= s["start_ns"] <= s["end_ns"] \
+                <= parent["end_ns"]
+        assert by("leaf")[0]["parent"] == by("inner")[0]["id"]
+        own = obs.self_times(list(spans.values()))
+        dur = lambda s: s["end_ns"] - s["start_ns"]
+        assert own[outer["id"]] == dur(outer) - sum(
+            dur(s) for s in by("inner"))
+        inner = by("inner")[0]
+        assert own[inner["id"]] == dur(inner) - dur(by("leaf")[0])
+        assert obs.snapshot()["timers"]["inner"]["count"] == 2
 
-    def test_stats_line_empty_window(self):
-        from repro.obs.reporting import stats_line
-        assert "tok_s=0.0" in stats_line(0, [], batch=4)
+    def test_engine_call_spans_each_layer_boundary(self):
+        obs.enable()
+        x = jnp.array(np.random.default_rng(7).integers(0, 9, 512), jnp.int32)
+        engine.argsort(x, variant="xla")
+        engine.argsort(x, variant="xla")
+        spans = obs.snapshot()["spans"]
+        roots = [s for s in spans if s["parent"] is None]
+        assert [s["name"] for s in roots] == ["engine.argsort"] * 2
+        assert roots[0]["call"] != roots[1]["call"]
+        for root in roots:
+            kids = [s["name"] for s in spans if s["parent"] == root["id"]]
+            assert kids == ["engine.validate", "engine.plan", "engine.guard",
+                            "engine.argsort.xla"]
+            assert all(s["call"] == root["call"] for s in spans
+                       if s["parent"] == root["id"])
+
+    def test_nested_public_call_shares_the_call_id(self):
+        obs.enable()
+        x = jnp.array(np.random.default_rng(8).integers(0, 9, 256), jnp.int32)
+        engine.sort(x, stable=True, variant="xla")
+        spans = obs.snapshot()["spans"]
+        assert len({s["call"] for s in spans}) == 1
+        inner = next(s for s in spans if s["name"] == "engine.argsort")
+        outer = next(s for s in spans if s["name"] == "engine.sort")
+        assert inner["parent"] == outer["id"]
+
+    def test_disabled_span_is_one_shared_noop(self):
+        assert obs.span("a") is obs.span("b")
+        with obs.span("a"):
+            pass
+        assert obs.snapshot()["spans"] == []
+
+    def test_report_renders_the_span_tree(self):
+        obs.enable()
+        with obs.span("engine.sort"):
+            with obs.span("engine.plan"):
+                pass
+        text = obs.report()
+        assert "spans: 2 in 1 calls" in text
+        assert re.search(r"\[call \d+\] engine\.sort ", text)
+        assert re.search(r"\[call \d+\]   engine\.plan ", text)
+
+
+class TestObsCompilesNoOtherProgram:
+    """Turning obs on stages nothing into traced code."""
+
+    @pytest.mark.parametrize("variant", ["xla", "fused"])
+    def test_moe_route_jaxpr(self, variant):
+        lg = jnp.asarray(np.random.default_rng(9).normal(size=(2, 64, 8)),
+                         jnp.float32)
+        f = lambda x: engine.moe_route(x, 2, 20, variant=variant)
+        off = str(jax.make_jaxpr(f)(lg))
+        obs.enable()
+        on = str(jax.make_jaxpr(f)(lg))
+        assert on == off
+
+    def test_kernel_argsort_jaxpr(self):
+        from repro.kernels.ops import kernel_argsort
+        x = jnp.array(np.random.default_rng(10).integers(0, 9, 2048),
+                      jnp.int32)
+        f = lambda k: kernel_argsort(k, chunk=128, w=32, interpret=True)
+        off = str(jax.make_jaxpr(f)(x))
+        obs.enable()
+        on = str(jax.make_jaxpr(f)(x))
+        assert on == off
