@@ -270,10 +270,18 @@ def _vmapped_reduce(keys, offsets, ranks, m: int, sched: MergeSchedule,
 
 
 def _pallas_reduce(keys, offsets, ranks, m: int, sched: MergeSchedule,
-                   descending: bool, interpret: bool):
+                   descending: bool, interpret: bool,
+                   uniform_len: Optional[int] = None):
     """Fused-pass tree: each pass collapses ``2^levels_per_pass`` runs per
     group in one ``pallas_call`` (the segmented pair kernel at one level,
     the merge-tree kernel at two or more).
+
+    Runs of one static length (``uniform_len``, else read from concrete
+    offsets) that are whole ``w``-wide rows, with no empty runs to pad a
+    group, keep that length through the passes; a pass whose groups are
+    also whole output blocks hands it to the kernel, which then builds its
+    banks by a pad and unpads by a reshape instead of gathering
+    (``bank="uniform"`` on the pass's event, else ``"gather"``).
 
     Stage scopes: pass ``i`` (from 0) runs under
     ``repro.schedule.pass<i>_L<levels>``, so a device trace counts the
@@ -286,6 +294,10 @@ def _pallas_reduce(keys, offsets, ranks, m: int, sched: MergeSchedule,
     m2 = _next_pow2(m)
     levels_total = m2.bit_length() - 1
     passes = 0
+    ulen = uniform_len if uniform_len is not None else _uniform_len(offsets)
+    if (ulen is None or ulen % sched.w or m2 != m
+            or ulen * (offsets.shape[0] - 1) != n):
+        ulen = None
     with obs.kernel_scope("schedule.run_bounds"):
         starts, lens = _pad_group_runs(offsets, m, m2)
     buf, rbuf = keys, ranks
@@ -295,33 +307,41 @@ def _pallas_reduce(keys, offsets, ranks, m: int, sched: MergeSchedule,
         # (G, C) block buffer stays O(n) even with many runs per pass
         groups = max(starts.shape[0] >> Lp, 1)
         bo = max(sched.w, min(sched.block_out, _next_pow2(-(-n // groups))))
+        # the kernels' block is ``bo`` itself when a multiple of ``w``
+        dense = (ulen is not None and bo % sched.w == 0
+                 and (ulen << Lp) % bo == 0)
+        bank = "uniform" if dense else "gather"
         obs.event("schedule.pass", executor="tree_pallas", levels=int(Lp),
                   runs=int(starts.shape[0]), n=int(n), block_out=int(bo),
-                  kv=rbuf is not None)
+                  kv=rbuf is not None, bank=bank)
+        obs.inc(f"schedule.bank.{bank}")
+        ul = ulen if dense else None
         with obs.kernel_scope(f"schedule.pass{passes}_L{Lp}"):
             if Lp == 1:
                 if rbuf is None:
                     buf = segmented_merge_runs(
                         buf, buf, starts[0::2], lens[0::2], starts[1::2],
                         lens[1::2], n_out=n, w=sched.w, block_out=bo,
-                        interpret=interpret)
+                        uniform_len=ul, interpret=interpret)
                 else:
                     buf, rbuf = segmented_merge_runs_kv(
                         buf, rbuf, buf, rbuf, starts[0::2], lens[0::2],
                         starts[1::2], lens[1::2], n_out=n, w=sched.w,
-                        block_out=bo, descending=descending,
+                        block_out=bo, descending=descending, uniform_len=ul,
                         interpret=interpret)
             else:
                 if rbuf is None:
                     buf = merge_tree_runs(
                         buf, starts, lens, group=1 << Lp, n_out=n, w=sched.w,
-                        block_out=bo, interpret=interpret)
+                        block_out=bo, uniform_len=ul, interpret=interpret)
                 else:
                     buf, rbuf = merge_tree_runs_kv(
                         buf, rbuf, starts, lens, group=1 << Lp, n_out=n,
                         w=sched.w, block_out=bo, descending=descending,
-                        interpret=interpret)
+                        uniform_len=ul, interpret=interpret)
         passes += 1
+        if ulen is not None:
+            ulen <<= Lp
         with obs.kernel_scope("schedule.run_bounds"):
             lens = lens.reshape(-1, 1 << Lp).sum(axis=1).astype(jnp.int32)
             starts = jnp.concatenate(
@@ -566,7 +586,7 @@ def merge_runs(keys, offsets, *, ranks=None, schedule: MergeSchedule,
             return _stream_reduce(keys, offsets, ranks, m, sched, descending,
                                   interpret, uniform_len=uniform_len)
     return _pallas_reduce(keys, offsets, ranks, m, sched, descending,
-                          interpret)
+                          interpret, uniform_len=uniform_len)
 
 
 def reduce_rows(rows, *, schedule: MergeSchedule, ranks=None,

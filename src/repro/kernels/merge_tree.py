@@ -35,6 +35,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -330,8 +331,9 @@ def _stage(name: str):
 
 def _merge_tree_call(buf, ranks, starts, lens, *, group: int, n_out: int,
                      w: int, block_out: int, descending: bool,
-                     interpret: bool):
-    from repro.kernels.segmented_merge import _build_bank, _unpad_blocks
+                     uniform_len: Optional[int], interpret: bool):
+    from repro.kernels.segmented_merge import (_build_bank, _uniform_bank,
+                                               _uniform_grid, _unpad_blocks)
 
     kv = ranks is not None
     R = starts.shape[0]
@@ -349,29 +351,47 @@ def _merge_tree_call(buf, ranks, starts, lens, *, group: int, n_out: int,
     Ha = C // w + L + 2
     Hp = pad_rows(Ha)                     # rows each leaf window DMAs
     ROWS = n_out // w + R * (Hp + 3)
+    G = n_out // C + n_groups             # >= sum ceil(group_len / C)
+    ell = uniform_len
+    if ell is not None:
+        assert ell % w == 0 and ell * R == n_out == buf.shape[0]
+        assert (group * ell) % C == 0
+        G = n_out // C                    # every step a real block
 
     # --- row-aligned banks (one shared bank, one DMA window per leaf) ------
     with _stage("bank"):
         starts = starts.astype(jnp.int32)
         lens = lens.astype(jnp.int32)
-        rows_per_run = -(-lens // w) + Hp + 2
-        row0 = jnp.concatenate([jnp.zeros((1,), jnp.int32),
-                                jnp.cumsum(rows_per_run)]).astype(jnp.int32)
         _, last_k = bound_keys(buf.dtype, descending)
-        kbank = _build_bank(buf, starts, lens, row0, ROWS, w, fill=last_k)
-        rbank = (_build_bank(ranks.astype(jnp.int32), starts, lens, row0,
-                             ROWS, w, fill=INVALID_RANK) if kv else None)
+        if ell is not None:
+            row0 = jnp.arange(R + 1, dtype=jnp.int32) * (ell // w + Hp + 2)
+            kbank = _uniform_bank(buf.reshape(R, ell // w, w), Hp + 2, ROWS,
+                                  fill=last_k)
+            rbank = (_uniform_bank(ranks.astype(jnp.int32).reshape(
+                R, ell // w, w), Hp + 2, ROWS, fill=INVALID_RANK)
+                if kv else None)
+        else:
+            rows_per_run = -(-lens // w) + Hp + 2
+            row0 = jnp.concatenate(
+                [jnp.zeros((1,), jnp.int32),
+                 jnp.cumsum(rows_per_run)]).astype(jnp.int32)
+            kbank = _build_bank(buf, starts, lens, row0, ROWS, w, fill=last_k)
+            rbank = (_build_bank(ranks.astype(jnp.int32), starts, lens, row0,
+                                 ROWS, w, fill=INVALID_RANK) if kv else None)
 
     # --- flat grid over (group, block) pairs -------------------------------
-    G = n_out // C + n_groups
     with _stage("grid"):
-        glen = lens.reshape(n_groups, group).sum(axis=1)
-        nb = -(-glen // C)
-        blk0 = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(nb)])
-        gsteps = jnp.arange(G, dtype=jnp.int32)
-        grp = jnp.clip(jnp.searchsorted(blk0, gsteps, side="right") - 1,
-                       0, n_groups - 1)
-        o = jnp.minimum((gsteps - blk0[grp]) * C, (glen[grp] // C) * C)
+        if ell is not None:
+            grp, o = _uniform_grid(G, group * ell, C)
+        else:
+            glen = lens.reshape(n_groups, group).sum(axis=1)
+            nb = -(-glen // C)
+            blk0 = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                    jnp.cumsum(nb)])
+            gsteps = jnp.arange(G, dtype=jnp.int32)
+            grp = jnp.clip(jnp.searchsorted(blk0, gsteps, side="right") - 1,
+                           0, n_groups - 1)
+            o = jnp.minimum((gsteps - blk0[grp]) * C, (glen[grp] // C) * C)
 
     # --- nested co-rank partition per grid step ----------------------------
     steps = max(1, math.ceil(math.log2(max(n_out, 2))) + 1)
@@ -404,34 +424,49 @@ def _merge_tree_call(buf, ranks, starts, lens, *, group: int, n_out: int,
 
     # --- gather padded blocks back to the flat group-order layout ----------
     with _stage("unpad"):
-        out = _unpad_blocks([o.reshape(G, C) for o in out], glen, blk0,
-                            n_out, G, C)
+        if ell is not None:
+            out = [o.reshape(-1) for o in out]
+        else:
+            out = _unpad_blocks([o.reshape(G, C) for o in out], glen, blk0,
+                                n_out, G, C)
         return tuple(out) if kv else out[0]
 
 
 @functools.partial(jax.jit, static_argnames=("group", "n_out", "w",
-                                             "block_out", "interpret"))
+                                             "block_out", "uniform_len",
+                                             "interpret"))
 @obs.scoped("kernels.merge_tree")
 def merge_tree_runs(buf, starts, lens, *, group: int, n_out: int, w: int = 32,
-                    block_out: int = 1024, interpret: bool = True):
+                    block_out: int = 1024, uniform_len: Optional[int] = None,
+                    interpret: bool = True):
     """Merge consecutive groups of ``group = 2^L`` descending runs — run ``r``
     is ``buf[starts[r] : starts[r] + lens[r]]`` — through ``L`` fused tree
     levels in ONE ``pallas_call``. Returns the (n_out,) concatenation of the
     merged groups in group order; ``n_out`` must equal ``sum(lens)`` (static
     contract). Ragged and empty runs are fine (their bank rows are sentinel).
+
+    ``uniform_len`` (static) says ``buf`` is ``n_out / uniform_len``
+    consecutive runs of exactly that length, a multiple of ``w`` (and each
+    group's output a multiple of the output block): the bank is then built
+    by a pad, the grid has no spare steps and the output needs no unpad
+    gather (``segmented_merge._uniform_bank``). ``None`` gathers them, for
+    ragged runs.
     """
     return _merge_tree_call(buf, None, starts, lens, group=group,
                             n_out=n_out, w=w, block_out=block_out,
-                            descending=True, interpret=interpret)
+                            descending=True, uniform_len=uniform_len,
+                            interpret=interpret)
 
 
 @functools.partial(jax.jit, static_argnames=("group", "n_out", "w",
                                              "block_out", "descending",
-                                             "interpret"))
+                                             "uniform_len", "interpret"))
 @obs.scoped("kernels.merge_tree_kv")
 def merge_tree_runs_kv(buf, ranks, starts, lens, *, group: int, n_out: int,
                        w: int = 32, block_out: int = 1024,
-                       descending: bool = True, interpret: bool = True):
+                       descending: bool = True,
+                       uniform_len: Optional[int] = None,
+                       interpret: bool = True):
     """Stable KV variant of ``merge_tree_runs``: (key, rank) lanes ride every
     level of the fused tree under the compound order (paper algorithm 3), so
     with ranks assigned in priority order the whole K-way reduction is
@@ -440,4 +475,5 @@ def merge_tree_runs_kv(buf, ranks, starts, lens, *, group: int, n_out: int,
     """
     return _merge_tree_call(buf, ranks, starts, lens, group=group,
                             n_out=n_out, w=w, block_out=block_out,
-                            descending=descending, interpret=interpret)
+                            descending=descending, uniform_len=uniform_len,
+                            interpret=interpret)

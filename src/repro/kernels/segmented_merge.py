@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import functools
 import math
+from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -97,6 +98,26 @@ def _build_bank(buf, starts, lens, row0, cap_rows: int, w: int, fill=None):
     return jnp.where(valid, buf[src], fill)
 
 
+def _uniform_bank(runs, pad: int, cap_rows: int, fill=None):
+    """Dense twin of ``_build_bank`` for runs of one static length ``ℓ``
+    (a multiple of ``w``): ``runs`` is (R, ℓ/w, w), each run is followed
+    by ``pad`` fill rows and the bank by fill rows up to ``cap_rows``.
+    Bit-identical to ``_build_bank`` with ``row0[s] = s·(ℓ/w + pad)``."""
+    fill = sentinel_for(runs.dtype) if fill is None else fill
+    bank = jnp.pad(runs, ((0, 0), (0, pad), (0, 0)), constant_values=fill)
+    bank = bank.reshape(-1, runs.shape[2])
+    return jnp.pad(bank, ((0, cap_rows - bank.shape[0]), (0, 0)),
+                   constant_values=fill)
+
+
+def _uniform_grid(G: int, group_len: int, C: int):
+    """(group, output offset) of each of the ``G`` steps of an exact grid
+    over groups of ``group_len`` elements, a multiple of ``C``."""
+    per = group_len // C                              # blocks per group
+    g = jnp.arange(G, dtype=jnp.int32)
+    return g // per, (g % per) * C
+
+
 def _corank_runs(o, la, lb, astart, bstart, a, b, steps: int):
     """Merge-path co-rank inside one (A-run, B-run) pair: #A-elements among
     the top-``o`` of the descending union, ties preferring B. ``la``/``lb``
@@ -154,10 +175,12 @@ def _unpad_blocks(outs, lo_len, blk0, n_out: int, G: int, C: int):
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("n_out", "w", "block_out", "interpret"))
+                   static_argnames=("n_out", "w", "block_out", "uniform_len",
+                                    "interpret"))
 @obs.scoped("kernels.segmented_merge_runs")
 def segmented_merge_runs(a, b, a_starts, a_lens, b_starts, b_lens, *,
                          n_out: int, w: int = 32, block_out: int = 1024,
+                         uniform_len: Optional[int] = None,
                          interpret: bool = True):
     """Merge R run pairs — ``a[a_starts[s]:+a_lens[s]]`` with
     ``b[b_starts[s]:+b_lens[s]]``, each descending — in ONE ``pallas_call``.
@@ -165,6 +188,14 @@ def segmented_merge_runs(a, b, a_starts, a_lens, b_starts, b_lens, *,
     Returns the (n_out,) concatenation of the merged runs in run order;
     ``n_out`` must equal ``sum(a_lens) + sum(b_lens)`` (static contract —
     callers derive it from shapes or static paddings).
+
+    ``uniform_len`` (static) says the pairs are the consecutive runs of one
+    buffer, each of that length ``ℓ``: ``a`` and ``b`` are the same (n_out,)
+    array of 2R runs, A-run s starts at ``2sℓ`` and B-run s at ``(2s+1)ℓ``.
+    ``ℓ`` must be a multiple of ``w`` and ``2ℓ`` of the output block. The
+    banks are then built by a pad, the grid has no spare steps and the
+    output needs no unpad gather (``_uniform_bank``); ``None`` gathers them
+    for ragged runs.
     """
     R = a_starts.shape[0]
     assert a.dtype == b.dtype and w & (w - 1) == 0
@@ -178,6 +209,11 @@ def segmented_merge_runs(a, b, a_starts, a_lens, b_starts, b_lens, *,
     G = n_out // C + R                    # >= sum ceil(out_len_s / C)
     RA = n_out // w + R * (Hp + 3)                    # static row capacity
     RB = RA
+    ell = uniform_len
+    if ell is not None:
+        assert ell % w == 0 and (2 * ell) % C == 0
+        assert 2 * ell * R == n_out == a.shape[0] == b.shape[0]
+        G = n_out // C
 
     # --- flat grid over (segment, block) pairs -----------------------------
     with _stage("grid"):
@@ -185,23 +221,38 @@ def segmented_merge_runs(a, b, a_starts, a_lens, b_starts, b_lens, *,
         b_starts = b_starts.astype(jnp.int32)
         la = a_lens.astype(jnp.int32)
         lb = b_lens.astype(jnp.int32)
-        lo_len = la + lb
-        nb = -(-lo_len // C)                          # blocks per segment
-        blk0 = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(nb)])
-        g = jnp.arange(G, dtype=jnp.int32)
-        seg = jnp.clip(jnp.searchsorted(blk0, g, side="right") - 1, 0, R - 1)
-        # tail steps past the last real block recompute segment-final
-        # co-ranks; their outputs are never gathered.
-        o = jnp.minimum((g - blk0[seg]) * C, (lo_len[seg] // C) * C)
+        if ell is not None:
+            seg, o = _uniform_grid(G, 2 * ell, C)
+        else:
+            lo_len = la + lb
+            nb = -(-lo_len // C)                      # blocks per segment
+            blk0 = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                    jnp.cumsum(nb)])
+            g = jnp.arange(G, dtype=jnp.int32)
+            seg = jnp.clip(jnp.searchsorted(blk0, g, side="right") - 1,
+                           0, R - 1)
+            # tail steps past the last real block recompute segment-final
+            # co-ranks; their outputs are never gathered.
+            o = jnp.minimum((g - blk0[seg]) * C, (lo_len[seg] // C) * C)
 
     # --- per-run row-aligned banks -----------------------------------------
     with _stage("bank"):
-        ra = -(-la // w) + Hp + 2
-        rb = -(-lb // w) + Hp + 2
-        ra0 = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(ra)])
-        rb0 = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(rb)])
-        abank = _build_bank(a, a_starts, la, ra0, RA, w)
-        bbank = _build_bank(b, b_starts, lb, rb0, RB, w)
+        if ell is not None:
+            rows = ell // w + Hp + 2                  # rows of each run
+            ra0 = rb0 = jnp.arange(R + 1, dtype=jnp.int32) * rows
+            abank = _uniform_bank(a.reshape(R, 2, ell // w, w)[:, 0],
+                                  Hp + 2, RA)
+            bbank = _uniform_bank(b.reshape(R, 2, ell // w, w)[:, 1],
+                                  Hp + 2, RB)
+        else:
+            ra = -(-la // w) + Hp + 2
+            rb = -(-lb // w) + Hp + 2
+            ra0 = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                   jnp.cumsum(ra)])
+            rb0 = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                   jnp.cumsum(rb)])
+            abank = _build_bank(a, a_starts, la, ra0, RA, w)
+            bbank = _build_bank(b, b_starts, lb, rb0, RB, w)
 
     # --- per-(segment, block) co-ranks (vectorised binary search) ----------
     steps = max(1, math.ceil(math.log2(max(n_out, 2))) + 1)
@@ -225,6 +276,8 @@ def segmented_merge_runs(a, b, a_starts, a_lens, b_starts, b_lens, *,
 
     # --- gather padded blocks back to the flat ragged layout ---------------
     with _stage("unpad"):
+        if ell is not None:
+            return out.reshape(-1)
         return _unpad_blocks([out], lo_len, blk0, n_out, G, C)[0]
 
 
@@ -300,18 +353,20 @@ def _corank_runs_kv(o, la, lb, astart, bstart, a, ra, b, rb, steps: int,
 
 @functools.partial(jax.jit,
                    static_argnames=("n_out", "w", "block_out", "descending",
-                                    "interpret"))
+                                    "uniform_len", "interpret"))
 @obs.scoped("kernels.segmented_merge_runs_kv")
 def segmented_merge_runs_kv(a, ra, b, rb, a_starts, a_lens, b_starts, b_lens,
                             *, n_out: int, w: int = 32, block_out: int = 1024,
-                            descending: bool = True, interpret: bool = True):
+                            descending: bool = True,
+                            uniform_len: Optional[int] = None,
+                            interpret: bool = True):
     """Stable KV variant of ``segmented_merge_runs``: merge R run pairs of
     (key, rank) lanes in ONE ``pallas_call``. Returns (keys, ranks).
 
-    Same flat (segment, block) grid, per-step co-ranks, and bank
-    layout as the keys-only kernel — the co-rank partition is
-    payload-oblivious, so the only change is one extra int32 bank per side
-    and the compound comparator end-to-end.
+    Same flat (segment, block) grid, per-step co-ranks, bank layout and
+    ``uniform_len`` contract as the keys-only kernel — the co-rank
+    partition is payload-oblivious, so the only change is one extra int32
+    bank per side and the compound comparator end-to-end.
     """
     R = a_starts.shape[0]
     assert a.dtype == b.dtype and w & (w - 1) == 0
@@ -327,29 +382,54 @@ def segmented_merge_runs_kv(a, ra, b, rb, a_starts, a_lens, b_starts, b_lens,
     G = n_out // C + R
     RA = n_out // w + R * (Hp + 3)
     RB = RA
+    ell = uniform_len
+    if ell is not None:
+        assert ell % w == 0 and (2 * ell) % C == 0
+        assert 2 * ell * R == n_out == a.shape[0] == b.shape[0]
+        G = n_out // C
 
     with _stage("grid"):
         a_starts = a_starts.astype(jnp.int32)
         b_starts = b_starts.astype(jnp.int32)
         la = a_lens.astype(jnp.int32)
         lb = b_lens.astype(jnp.int32)
-        lo_len = la + lb
-        nb = -(-lo_len // C)
-        blk0 = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(nb)])
-        g = jnp.arange(G, dtype=jnp.int32)
-        seg = jnp.clip(jnp.searchsorted(blk0, g, side="right") - 1, 0, R - 1)
-        o = jnp.minimum((g - blk0[seg]) * C, (lo_len[seg] // C) * C)
+        if ell is not None:
+            seg, o = _uniform_grid(G, 2 * ell, C)
+        else:
+            lo_len = la + lb
+            nb = -(-lo_len // C)
+            blk0 = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                    jnp.cumsum(nb)])
+            g = jnp.arange(G, dtype=jnp.int32)
+            seg = jnp.clip(jnp.searchsorted(blk0, g, side="right") - 1,
+                           0, R - 1)
+            o = jnp.minimum((g - blk0[seg]) * C, (lo_len[seg] // C) * C)
 
     with _stage("bank"):
-        rra = -(-la // w) + Hp + 2
-        rrb = -(-lb // w) + Hp + 2
-        ra0 = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(rra)])
-        rb0 = jnp.concatenate([jnp.zeros((1,), jnp.int32), jnp.cumsum(rrb)])
         _, lastK = bound_keys(a.dtype, descending)
-        abank = _build_bank(a, a_starts, la, ra0, RA, w, fill=lastK)
-        bbank = _build_bank(b, b_starts, lb, rb0, RB, w, fill=lastK)
-        arbank = _build_bank(ra, a_starts, la, ra0, RA, w, fill=INVALID_RANK)
-        brbank = _build_bank(rb, b_starts, lb, rb0, RB, w, fill=INVALID_RANK)
+        if ell is not None:
+            rows = ell // w + Hp + 2                  # rows of each run
+            ra0 = rb0 = jnp.arange(R + 1, dtype=jnp.int32) * rows
+            pairs = lambda x, side: x.reshape(R, 2, ell // w, w)[:, side]
+            abank = _uniform_bank(pairs(a, 0), Hp + 2, RA, fill=lastK)
+            bbank = _uniform_bank(pairs(b, 1), Hp + 2, RB, fill=lastK)
+            arbank = _uniform_bank(pairs(ra, 0), Hp + 2, RA,
+                                   fill=INVALID_RANK)
+            brbank = _uniform_bank(pairs(rb, 1), Hp + 2, RB,
+                                   fill=INVALID_RANK)
+        else:
+            rra = -(-la // w) + Hp + 2
+            rrb = -(-lb // w) + Hp + 2
+            ra0 = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                   jnp.cumsum(rra)])
+            rb0 = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                   jnp.cumsum(rrb)])
+            abank = _build_bank(a, a_starts, la, ra0, RA, w, fill=lastK)
+            bbank = _build_bank(b, b_starts, lb, rb0, RB, w, fill=lastK)
+            arbank = _build_bank(ra, a_starts, la, ra0, RA, w,
+                                 fill=INVALID_RANK)
+            brbank = _build_bank(rb, b_starts, lb, rb0, RB, w,
+                                 fill=INVALID_RANK)
 
     steps = max(1, math.ceil(math.log2(max(n_out, 2))) + 1)
     with _stage("corank"):
@@ -373,6 +453,8 @@ def segmented_merge_runs_kv(a, ra, b, rb, a_starts, a_lens, b_starts, b_lens,
                              interpret=interpret, descending=descending)
 
     with _stage("unpad"):
+        if ell is not None:
+            return ok.reshape(-1), orr.reshape(-1)
         return tuple(_unpad_blocks([ok, orr], lo_len, blk0, n_out, G, C))
 
 

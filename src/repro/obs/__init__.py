@@ -22,7 +22,10 @@ What gets recorded (the event taxonomy — DESIGN.md §7.1):
 - ``autotune.winner``     the installed plan and its median time
 - ``schedule.pass``       each fused merge-tree pass (executor, levels, runs;
   ``level_kind='hbm_run'`` on the streaming executors whose runs live in
-  HBM rather than a scratch bank)
+  HBM rather than a scratch bank; on ``tree_pallas`` ``bank='uniform'``
+  where the pass's banks are a dense pad of uniform runs, else
+  ``'gather'``, tallied by the ``schedule.bank.uniform`` /
+  ``schedule.bank.gather`` counters)
 - ``schedule.reduce``     one per reduction: passes vs tree levels (the HBM
   round trips a fused schedule saved)
 - ``external.run_form``   out-of-core phase 1: tiles sorted into runs, with

@@ -363,3 +363,201 @@ def test_flims_sort_large_n_no_padded_bank_blowup():
     x = np.random.default_rng(12).integers(-2**31, 2**31 - 1, n)
     out = flims_sort(jnp.array(x.astype(np.int32)), chunk=512, w=64)
     np.testing.assert_array_equal(np.array(out), np.sort(x)[::-1])
+
+
+# --------------------------------------------------------------------------
+# uniform runs: dense bank, exact grid and reshape unpad vs the gathers
+# --------------------------------------------------------------------------
+
+@pytest.fixture
+def fresh_executables():
+    """Release the module's compiled programs before the test. Each CPU
+    executable holds hundreds of memory maps, and by this point of the
+    module the earlier tests' programs have used most of the process's
+    map limit (65,530): the next compile would fail in the native
+    compiler. The tests below share no program with those above."""
+    jax.clear_caches()
+
+
+#: (run length ℓ, w, output block C, tree levels L): ℓ = w, ℓ < C, ℓ > C
+UNIFORM_GEOMS = [(8, 8, 64, 2), (16, 8, 64, 1), (128, 8, 32, 2),
+                 (64, 16, 32, 3)]
+#: (dtype, fill) of each lane: key banks take the direction's last key,
+#: rank banks INVALID_RANK
+UNIFORM_LANES = {
+    "int32_desc": (np.int32, True), "int32_asc": (np.int32, False),
+    "float32_desc": (np.float32, True), "float32_asc": (np.float32, False),
+    "rank": (np.int32, None),
+}
+
+
+@pytest.mark.usefixtures("fresh_executables")
+@pytest.mark.parametrize("layout", ["tree", "pair"])
+@pytest.mark.parametrize("lane", sorted(UNIFORM_LANES))
+@pytest.mark.parametrize("ell,w,C,L", UNIFORM_GEOMS)
+def test_uniform_bank_matches_gathered_bank(ell, w, C, L, lane, layout):
+    """The dense pad of uniform runs is ``_build_bank``'s layout bit for
+    bit: the merge tree's one bank of every run, and the pair merge's
+    A-bank of the even runs and B-bank of the odd ones."""
+    from repro.core.lanes import INVALID_RANK
+    from repro.kernels.flims_merge import bound_keys, pad_rows
+    from repro.kernels.segmented_merge import _build_bank, _uniform_bank
+    dtype, descending = UNIFORM_LANES[lane]
+    fill = (INVALID_RANK if descending is None
+            else bound_keys(dtype, descending)[1])
+    R = 2 << L                                   # two groups of 2^L runs
+    n = R * ell
+    # the kernels' window heights: merge tree C/w + L + 2, pair C/w + 2
+    Hp = pad_rows(C // w + (L if layout == "tree" else 0) + 2)
+    rows = ell // w + Hp + 2
+    step = 1 if layout == "tree" else 2          # pair banks: every other run
+    Rs = R // step                               # runs in each bank
+    cap_rows = n // w + Rs * (Hp + 3)
+
+    @jax.jit
+    def banks(buf):
+        """(dense, gathered, row0) of each bank of ``layout``."""
+        out = []
+        for side in range(step):
+            runs = buf.reshape(Rs, step, ell // w, w)[:, side]
+            ln = jnp.full((Rs,), ell, jnp.int32)
+            starts = (jnp.arange(Rs, dtype=jnp.int32) * step + side) * ell
+            row0 = jnp.concatenate([jnp.zeros((1,), jnp.int32),
+                                    jnp.cumsum(-(-ln // w) + Hp + 2)])
+            out.append((_uniform_bank(runs, Hp + 2, cap_rows, fill=fill),
+                        _build_bank(buf, starts, ln, row0, cap_rows, w,
+                                    fill=fill), row0))
+        return out
+
+    buf = jnp.array(RNG.integers(-50, 50, n).astype(dtype))
+    for got, want, row0 in banks(buf):
+        np.testing.assert_array_equal(np.array(row0), np.arange(Rs + 1) * rows)
+        got, want = np.array(got), np.array(want)
+        assert got.dtype == want.dtype and got.shape == want.shape
+        np.testing.assert_array_equal(got.view(np.int32),
+                                      want.view(np.int32))
+
+
+@pytest.mark.usefixtures("fresh_executables")
+@pytest.mark.parametrize("n,glen,C", [(1 << 10, 64, 64), (1 << 10, 256, 64),
+                                      (1 << 11, 512, 256)])
+def test_uniform_unpad_matches_gather(n, glen, C):
+    """For groups of one length, a multiple of ``C``, ``_unpad_blocks``
+    reads only the first ``n / C`` blocks, in order: a reshape of the exact
+    grid's output; ``_uniform_grid`` names those blocks' groups/offsets."""
+    from repro.kernels.segmented_merge import _uniform_grid, _unpad_blocks
+    n_groups = n // glen
+    G = n // C + n_groups                        # the gather path's grid
+    out = jnp.array(RNG.integers(-99, 99, (G, C)).astype(np.int32))
+    lo_len = jnp.full((n_groups,), glen, jnp.int32)
+    blk0 = jnp.arange(n_groups + 1, dtype=jnp.int32) * (glen // C)
+    (want,) = _unpad_blocks([out], lo_len, blk0, n, G, C)
+    np.testing.assert_array_equal(np.array(out[:n // C].reshape(-1)),
+                                  np.array(want))
+    grp, o = _uniform_grid(n // C, glen, C)
+    g = np.arange(n // C)
+    np.testing.assert_array_equal(np.array(grp), g // (glen // C))
+    np.testing.assert_array_equal(np.array(o), (g % (glen // C)) * C)
+
+
+def _uniform_call(kernel, keys, ranks, ell, block_out, descending, ul):
+    """One uniform-runs call of a pass kernel, with ``uniform_len=ul``."""
+    from repro.kernels.merge_tree import merge_tree_runs, merge_tree_runs_kv
+    from repro.kernels.segmented_merge import (segmented_merge_runs,
+                                               segmented_merge_runs_kv)
+    n = keys.shape[0]
+    K = n // ell
+    starts = jnp.arange(K, dtype=jnp.int32) * ell
+    lens = jnp.full((K,), ell, jnp.int32)
+    kw = dict(n_out=n, w=8, block_out=block_out, uniform_len=ul)
+    pair = (starts[0::2], lens[0::2], starts[1::2], lens[1::2])
+    if kernel == "merge_tree":
+        return (merge_tree_runs(keys, starts, lens, group=4, **kw),)
+    if kernel == "merge_tree_kv":
+        return merge_tree_runs_kv(keys, ranks, starts, lens, group=4,
+                                  descending=descending, **kw)
+    if kernel == "segmented":
+        return (segmented_merge_runs(keys, keys, *pair, **kw),)
+    return segmented_merge_runs_kv(keys, ranks, keys, ranks, *pair,
+                                   descending=descending, **kw)
+
+
+@pytest.mark.usefixtures("fresh_executables")
+@pytest.mark.parametrize("ell,block_out", [(32, 64), (64, 32)])
+@pytest.mark.parametrize("kernel,descending", [
+    ("merge_tree", True), ("merge_tree_kv", True), ("merge_tree_kv", False),
+    ("segmented", True), ("segmented_kv", True), ("segmented_kv", False)])
+def test_uniform_path_matches_gather_path(kernel, descending, ell,
+                                          block_out):
+    """With and without ``uniform_len``, the same uniform heavy-tie runs
+    merge to identical keys and ranks (interpret mode)."""
+    K = 8
+    rows = np.sort(RNG.integers(0, 3, (K, ell)).astype(np.int32), axis=1)
+    if descending:
+        rows = rows[:, ::-1]
+    keys = jnp.array(rows.reshape(-1))
+    ranks = jnp.arange(K * ell, dtype=jnp.int32)
+    gather = _uniform_call(kernel, keys, ranks, ell, block_out, descending,
+                           None)
+    dense = _uniform_call(kernel, keys, ranks, ell, block_out, descending,
+                          ell)
+    for g, d in zip(gather, dense):
+        np.testing.assert_array_equal(np.array(d), np.array(g))
+    group = 2 if kernel.startswith("segmented") else 4
+    want = np.concatenate([np.sort(x)[::-1] if descending else np.sort(x)
+                           for x in rows.reshape(-1, group * ell)])
+    np.testing.assert_array_equal(np.array(dense[0]), want)
+
+
+@pytest.mark.usefixtures("fresh_executables")
+@pytest.mark.parametrize("dtype", [np.int32, np.float32])
+@pytest.mark.parametrize("descending", [True, False])
+def test_kernel_argsort_uniform_passes_ragged_n(dtype, descending):
+    """``kernel_argsort`` at an n that is no multiple of its chunk (its
+    merge passes all take the dense path) is ``jnp.argsort(stable=True)``
+    bit for bit on heavy ties."""
+    from repro.kernels.ops import kernel_argsort
+    keys = jnp.array(RNG.integers(0, 4, 1000).astype(dtype))
+    got = kernel_argsort(keys, chunk=64, w=32, descending=descending,
+                         interpret=True)
+    np.testing.assert_array_equal(
+        np.array(got),
+        np.array(jnp.argsort(keys, stable=True, descending=descending)))
+
+
+@pytest.mark.usefixtures("fresh_executables")
+def test_pallas_passes_report_their_bank_path():
+    """Under obs, each ``tree_pallas`` pass says which bank it built: every
+    pass of ``kernel_argsort`` (traced under jit) the dense ``uniform`` one,
+    a ragged ``merge_runs`` the ``gather`` one; the two counters add up to
+    the passes."""
+    from repro import obs
+    from repro.kernels.ops import kernel_argsort
+
+    def passes():
+        snap = obs.snapshot()
+        ev = [e["data"]["bank"] for e in snap["events"]
+              if e["kind"] == "schedule.pass"
+              and e["data"]["executor"] == "tree_pallas"]
+        c = snap["counters"]
+        assert (c.get("schedule.bank.uniform", 0)
+                + c.get("schedule.bank.gather", 0)) == len(ev)
+        return ev
+
+    obs.reset()
+    obs.enable()
+    try:
+        kernel_argsort.clear_cache()         # the events fire at trace time
+        keys = jnp.array(RNG.integers(0, 4, 1000).astype(np.int32))
+        kernel_argsort(keys, chunk=64, w=32, interpret=True)
+        ev = passes()
+        assert ev and set(ev) == {"uniform"}
+        obs.reset()
+        buf, offs = _runs([30, 0, 12, 7, 64, 9, 1, 40], np.int32)
+        engine.merge_runs(jnp.array(buf), jnp.array(offs),
+                          variant="tree_pallas")
+        ev = passes()
+        assert ev and set(ev) == {"gather"}
+    finally:
+        obs.disable()
+        obs.reset()

@@ -51,7 +51,8 @@ def tpu_plan(op, n, dtype=jnp.int32, segments=0, **kw):
 # sizes are the smallest that give each variant its TPU-default geometry,
 # plus those of chip_smoke.py where the limits that grow with the input
 # bind: per-step tables past SMEM's 1 MiB (sort at 2^24 keys) and the
-# stream merge's grid cut into calls of at most 4096 steps (2^27 keys)
+# stream merge's grid cut into calls of at most 4096 steps (2^27 keys),
+# and the TPC-H SF1 LINEITEM row count the argsort benchmark cell sorts
 N = 1 << 15
 
 
@@ -62,10 +63,12 @@ def _sort(dtype, n=N):
     return "sort", dict(n=n, dtype=dtype), build
 
 
-def _argsort(plan):
-    fn = registry.get("argsort", plan.variant)
-    return (lambda x: fn(x, plan=plan, descending=True, interpret=False),
-            [((N,), jnp.int32)])
+def _argsort(n=N):
+    def build(plan):
+        fn = registry.get("argsort", plan.variant)
+        return (lambda x: fn(x, plan=plan, descending=True, interpret=False),
+                [((n,), jnp.int32)])
+    return "argsort", dict(n=n), build
 
 
 def _merge(n=N):
@@ -120,7 +123,8 @@ CASES = {
     "sort-int32": _sort(jnp.int32),
     "sort-float32": _sort(jnp.float32),
     "sort-int32-2^24": _sort(jnp.int32, 1 << 24),
-    "argsort": ("argsort", dict(n=N), _argsort),
+    "argsort": _argsort(),
+    "argsort-6001215": _argsort(6_001_215),
     "merge": _merge(),
     "merge-2x2^23": _merge(1 << 24),
     "segment_sort": _segment("segment_sort"),
